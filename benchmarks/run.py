@@ -338,6 +338,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if len(sys.argv) > 1 and sys.argv[1] == "whatif":
         print(json.dumps(whatif_snapshot(), indent=2))
     elif len(sys.argv) > 1 and sys.argv[1] == "des":

@@ -850,12 +850,10 @@ def scenario_mesh(num_devices: int | None = None):
     start to split the host into N devices (the ``tier1-multidevice`` CI job
     runs the equivalence suite exactly that way).
     """
-    from repro.parallel.sharding import make_mesh_compat
-
     devs = jax.devices()  # tracecheck: disable=TC007 — mesh discovery is this helper's purpose
     n = len(devs) if num_devices is None else int(num_devices)
-    return make_mesh_compat((n,), (SCENARIO_AXIS,),
-                            devices=np.array(devs[:n]))
+    return jax.make_mesh((n,), (SCENARIO_AXIS,),
+                         (jax.sharding.AxisType.Auto,), devices=devs[:n])
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "max_hosts", "t_bins",
@@ -877,7 +875,6 @@ def _run_scenarios_sharded_jit(
     use_pallas: bool = False,
     precision: str = "f32",
 ) -> tuple[SimOutput, Prediction]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(ss_local: ScenarioSet, ci_local: Array | None,
@@ -888,12 +885,12 @@ def _run_scenarios_sharded_jit(
             max_starts_per_bin=max_starts_per_bin, model=model, chunk=chunk,
             use_pallas=use_pallas, precision=precision)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         # S-axis sharded; the [T] traces replicated on every device
         in_specs=(P(SCENARIO_AXIS), P(), P(), P()),
         out_specs=P(SCENARIO_AXIS),
-        check_rep=False,
+        check_vma=False,
     )(ss, carbon_intensity, ambient_c, price)
 
 
@@ -1051,10 +1048,10 @@ def run_scenarios(
     n_dev = mesh.shape[SCENARIO_AXIS]
     per_dev = -(-s // n_dev)
     if n_dev > 1:
-        # keep >= 2 lanes per device: a batch-1 vmapped while_loop inside
-        # shard_map trips an XLA sharding-propagation bug on jax 0.4.x
-        # ("tile_assignment should have N devices" on the backfill skip-mask
-        # iota) — one extra masked replica lane per device sidesteps it.
+        # keep >= 2 lanes per device: on jax 0.9.0 a batch-1 vmap compiles
+        # to a different program than the batch-S one, and with carbon and
+        # price traces its gco2 / energy_cost leaves differ from the vmap
+        # path's by 1 ulp — one masked replica lane keeps the bitwise gate.
         per_dev = max(per_dev, 2)
     padded = _pad_scenario_axis(anon, per_dev * n_dev - s)
     # readout chunking is resolved from the *global* (unpadded) batch so the

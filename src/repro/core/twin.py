@@ -351,21 +351,21 @@ def fleet_mesh(num_devices: int | None = None):
     start to split the host into N devices (the ``tier1-multidevice`` CI job
     runs the fleet equivalence suite exactly that way).
     """
-    from repro.parallel.sharding import make_mesh_compat
-
     devs = jax.devices()  # tracecheck: disable=TC007 — mesh discovery is this helper's purpose
     n = len(devs) if num_devices is None else int(num_devices)
-    return make_mesh_compat((n,), (FLEET_AXIS,), devices=np.array(devs[:n]))
+    return jax.make_mesh((n,), (FLEET_AXIS,), (jax.sharding.AxisType.Auto,),
+                         devices=devs[:n])
 
 
 def _fleet_pad(d: int, mesh) -> int:
-    """Lanes to add so every device holds an equal, safe shard of D."""
+    """Lanes to add so every device holds an equal shard of D."""
     n_dev = mesh.shape[FLEET_AXIS]
     per_dev = -(-d // n_dev)
     if n_dev > 1:
-        # keep >= 2 lanes per device: a batch-1 vmapped while_loop inside
-        # shard_map trips an XLA sharding-propagation bug on jax 0.4.x —
-        # same workaround as the scenario engine's S axis.
+        # keep >= 2 lanes per device: on four TPU v5e chips (jax 0.9.0) a
+        # batch-1 vmap compiles to a different program than the batch-D
+        # one, and its mape / calib_mape differ from the vmap path's by
+        # 1 ulp — one inactive replica lane keeps the bitwise gate.
         per_dev = max(per_dev, 2)
     return per_dev * n_dev - d
 
@@ -402,30 +402,28 @@ def _commit_to_mesh(tree, mesh, axis: int):
 
 @functools.partial(jax.jit, static_argnames=("mesh",))
 def _run_fleet_sharded_jit(fleet, telemetry, sim_slices, *, mesh):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(
+    return jax.shard_map(
         _run_fleet, mesh=mesh,
         # fleet-state leaves lead with D; telemetry/sim leaves are [W, D, ..]
         in_specs=(P(FLEET_AXIS), P(None, FLEET_AXIS), P(None, FLEET_AXIS)),
         out_specs=(P(FLEET_AXIS), P(None, FLEET_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )(fleet, telemetry, sim_slices)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh",))
 def _fleet_step_masked_sharded_jit(fleet, telemetry, sim_slices, lane_active,
                                    *, mesh):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    return shard_map(
+    return jax.shard_map(
         _fleet_step_masked, mesh=mesh,
         # one window: every input/output leaf leads with the D axis
         in_specs=(P(FLEET_AXIS),) * 4,
         out_specs=(P(FLEET_AXIS), P(FLEET_AXIS)),
-        check_rep=False,
+        check_vma=False,
     )(fleet, telemetry, sim_slices, lane_active)
 
 

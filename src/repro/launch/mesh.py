@@ -5,8 +5,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.parallel.sharding import make_mesh_compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 (one v5e-class pod) or 2x16x16 (two pods, 512 chips)."""
@@ -21,9 +19,13 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count"
         )
-    return make_mesh_compat(shape, axes, devices=devices[:n])
+    return jax.make_mesh(shape, axes,
+                         (jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices[:n])
 
 
 def make_host_mesh():
     """Single-device 'mesh' for smoke tests (1x1 data/model)."""
-    return make_mesh_compat((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2,
+                         devices=jax.devices()[:1])

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.core import OrchestratorConfig, run_surf_experiment
 from repro.core.calibrate import CalibrationSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.traces.schema import DatacenterConfig
 from repro.traces.surf import BINS_PER_DAY, SurfTraceSpec, make_surf22_like
 
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=22)
     ap.set_defaults(calibrate=True)
     args = ap.parse_args()
+    enable_compile_cache()
 
     dc = DatacenterConfig()
     w = make_surf22_like(SurfTraceSpec(days=args.days, seed=args.seed), dc)

@@ -23,32 +23,6 @@ from jax.sharding import PartitionSpec as P
 LogicalAxes = tuple[str | None, ...]
 
 
-def make_mesh_compat(shape, axes, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax grew an ``axis_types`` kwarg (and ``jax.sharding.AxisType``);
-    older releases (<= 0.4.x) have neither.  Explicit-Auto is the default
-    everywhere, so omitting it on old jax is behavior-identical.
-    """
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(shape)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-
-
-def abstract_mesh_compat(shape, axes):
-    """``jax.sharding.AbstractMesh`` across jax versions.
-
-    New API: ``AbstractMesh(shape, axis_names)``; 0.4.x API:
-    ``AbstractMesh(tuple of (name, size) pairs)``.
-    """
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
 #: mode -> logical axis -> mesh axis (or tuple of mesh axes)
 RULES: dict[str, dict[str, Any]] = {
     "train": {

@@ -12,10 +12,9 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 from jax.sharding import Mesh
-
-from repro.parallel.sharding import make_mesh_compat
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,4 +68,6 @@ def build_mesh(plan: MeshPlan, devices) -> Mesh:
     (tracecheck TC007 — the runtime layer is deterministic-core).
     """
     n = int(np.prod(plan.shape))
-    return make_mesh_compat(plan.shape, plan.axes, devices=devices[:n])
+    return jax.make_mesh(plan.shape, plan.axes,
+                         (jax.sharding.AxisType.Auto,) * len(plan.shape),
+                         devices=devices[:n])

@@ -81,16 +81,15 @@ def test_sharding_rules():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.sharding import (
-        abstract_mesh_compat, logical_to_spec, make_mesh_compat,
-    )
+    from repro.parallel.sharding import logical_to_spec
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     # trivial mesh: everything replicated
     assert logical_to_spec(("batch", "embed"), (8, 16), mesh, "train") == P()
 
     # fake bigger mesh via abstract mesh
-    mesh = abstract_mesh_compat((4, 2), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     spec = logical_to_spec(("batch", "ff"), (8, 16), mesh, "train")
     assert spec == P(("data",), "model") or spec == P("data", "model")
     # non-divisible dims drop their sharding
@@ -99,6 +98,31 @@ def test_sharding_rules():
     # an axis is consumed at most once
     spec = logical_to_spec(("ff", "vocab"), (16, 32), mesh, "train")
     assert spec == P("model")
+
+
+def test_compile_cache_directory(monkeypatch, tmp_path):
+    """The chip entry points' cache: JAX_COMPILATION_CACHE_DIR wins and is
+    left to JAX; unset, the cache goes to the fixed <repo>/.jax_cache."""
+    import pathlib
+
+    import jax
+
+    from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert REPO_CACHE_DIR == (
+            pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_moe_capacity_and_gates():
@@ -140,9 +164,8 @@ HLO_SUBPROC = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.analysis.hlo import analyze_compiled_text
-    from repro.parallel.sharding import make_mesh_compat
-
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     L, B, D, F = 6, 8, 64, 128
 
     def step(ws, x):

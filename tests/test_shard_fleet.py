@@ -141,10 +141,9 @@ def test_explicit_mesh_and_padding():
 
 
 def test_one_lane_per_device():
-    """Regression: D == device count (one lane per device) used to be the
-    shape that hit the jax-0.4.x batch-1 vmapped-while_loop bug inside
-    shard_map; the engine pads to >= 2 lanes per device and must still
-    match the vmap path bit for bit."""
+    """D == device count: the engine pads to >= 2 lanes per device (a
+    batch-1 vmap is not bitwise on TPU) and must still match the vmap
+    path bit for bit."""
     d = len(jax.devices())
     telem, sims = _step_inputs(d, seed0=40)
     active = jnp.ones((d,), bool)

@@ -138,10 +138,9 @@ def test_sharded_matches_vmap_new_axes(workload):
 
 
 def test_one_lane_per_device_with_backfill(workload):
-    """Regression: S == device count with backfill compiled in used to hit
-    an XLA 0.4.x sharding-propagation bug (batch-1 vmapped while_loop inside
-    shard_map); the engine pads to >= 2 lanes per device to sidestep it and
-    must still match the vmap path bit for bit."""
+    """S == device count with backfill compiled in: the engine pads to
+    >= 2 lanes per device and must still match the vmap path bit for
+    bit."""
     n_dev = len(jax.devices())
     scs = [Scenario(name=f"s{i}", num_hosts=16 + 2 * i,
                     backfill_depth=2 if i == 1 else 0)
@@ -150,6 +149,23 @@ def test_one_lane_per_device_with_backfill(workload):
     ref = run_scenarios(ss, max_hosts=ss.max_hosts, t_bins=T_BINS)
     sh = run_scenarios(ss, max_hosts=ss.max_hosts, t_bins=T_BINS, shard=True)
     _assert_trees_equal(ref, sh)
+
+
+def test_one_lane_per_device_with_carbon_and_price(workload):
+    """S == device count with carbon and price traces: a batch-1 vmap per
+    device would differ from the vmap path by 1 ulp in gco2/energy_cost on
+    jax 0.9.0, so the engine's >= 2 lanes per device keep it bitwise."""
+    n_dev = len(jax.devices())
+    ci = make_diurnal_carbon(T_BINS, seed=1)
+    pr = make_diurnal_price(T_BINS, seed=3)
+    scs = [Scenario(name=f"s{i}", power_cap_w=5000.0 + 500.0 * i,
+                    backfill_depth=2 if i == 1 else 0)
+           for i in range(n_dev)]
+    ss = build_scenario_set(workload, DC, scs)
+    kw = dict(max_hosts=ss.max_hosts, t_bins=T_BINS, carbon_intensity=ci,
+              price=pr)
+    _assert_trees_equal(run_scenarios(ss, **kw),
+                        run_scenarios(ss, **kw, shard=True))
 
 
 def test_multidevice_actually_shards(workload):
