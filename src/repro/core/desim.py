@@ -414,70 +414,80 @@ def simulate_utilization_masked(
                          next_job=next_job, skip=skip, release=release)
         return new_state, None
 
-    state, _ = jax.lax.scan(
-        step, init, jnp.arange(t_bins, dtype=jnp.int32)
-    )
+    # named scopes: stable names for the device time of the placement
+    # scan and the read-out in a profiler trace (HLO metadata only)
+    with jax.named_scope("opendt.des_scan"):
+        state, _ = jax.lax.scan(
+            step, init, jnp.arange(t_bins, dtype=jnp.int32)
+        )
     job_start, job_host = state["job_start"], state["job_host"]
 
     # -- vectorized post-scan read-out ---------------------------------------
-    # Reconstructs exactly what the old per-bin accumulation produced:
-    # integer counts are exact, and the float utilization scatter-adds in the
-    # same job order as the per-bin segment-sum did.  Bins are processed in
-    # blocks of _READOUT_BLOCK so the dense [jobs, bins] intermediates stay
-    # bounded at O(jobs * block) per scenario (under the scenario vmap the
-    # full-horizon version would materialize [S, jobs, bins] arrays).
-    u_phases = w.num_phases
-    started = job_start >= 0                           # [J]
-    st = job_start[:, None]                            # [J, 1]
-    du = dur[:, None]
-    seg = jnp.where(started, job_host, max_hosts)      # sentinel bucket
-    if fail_start is not None:
-        # per-job effective end: killed jobs (placed pre-outage on a
-        # kill-host, overlapping its window) stop at fail_start.  Mirrors
-        # the release-table kill rule above.
-        h_j = jnp.where(started, job_host, 0)
-        fs_j = fail_start[h_j][:, None]                # [J, 1]
-        kill_j = (fail_kill[h_j] & started)[:, None]
-        killed_j = kill_j & (st < fs_j) & (st + du > fs_j)
-        end_eff = jnp.where(killed_j, fs_j, st + du)
-    else:
-        end_eff = st + du
+    with jax.named_scope("opendt.des_expand"):
+        # Reconstructs exactly what the old per-bin accumulation produced:
+        # integer counts are exact, and the float utilization scatter-adds
+        # in the same job order as the per-bin segment-sum did.  Bins are
+        # processed in blocks of _READOUT_BLOCK so the dense [jobs, bins]
+        # intermediates stay bounded at O(jobs * block) per scenario (under
+        # the scenario vmap the full-horizon version would materialize
+        # [S, jobs, bins] arrays).
+        u_phases = w.num_phases
+        started = job_start >= 0                           # [J]
+        st = job_start[:, None]                            # [J, 1]
+        du = dur[:, None]
+        seg = jnp.where(started, job_host, max_hosts)      # sentinel bucket
+        if fail_start is not None:
+            # per-job effective end: killed jobs (placed pre-outage on a
+            # kill-host, overlapping its window) stop at fail_start.
+            # Mirrors the release-table kill rule above.
+            h_j = jnp.where(started, job_host, 0)
+            fs_j = fail_start[h_j][:, None]                # [J, 1]
+            kill_j = (fail_kill[h_j] & started)[:, None]
+            killed_j = kill_j & (st < fs_j) & (st + du > fs_j)
+            end_eff = jnp.where(killed_j, fs_j, st + du)
+        else:
+            end_eff = st + du
 
-    def readout_block(tt):
-        # tt [B] with -1 padding past the horizon (matches nothing below)
-        running = started[:, None] & (tt >= st) & (tt < end_eff)   # [J, B]
-        phase = jnp.clip((tt - st) * u_phases // jnp.maximum(du, 1),
-                         0, u_phases - 1)
-        u_job = jnp.take_along_axis(w.util_levels, phase, axis=1)  # [J, B]
-        busy = jnp.where(
-            running, u_job * cores[:, None].astype(u_job.dtype), 0.0)
-        host_busy = jax.ops.segment_sum(
-            busy, seg, num_segments=max_hosts + 1)[:max_hosts]     # [H, B]
-        u_b = host_busy.T / jnp.maximum(cores_per_host, 1).astype(
-            host_busy.dtype)
-        started_by_t = started[:, None] & (tt >= st)               # [J, B]
-        queued = jnp.sum(
-            (submit[:, None] <= tt) & valid[:, None]
-            & jnp.logical_not(started_by_t), axis=0).astype(jnp.int32)
-        running_ct = jnp.sum(running, axis=0).astype(jnp.int32)
-        return u_b, queued, running_ct
+        def readout_block(tt):
+            # tt [B] with -1 padding past the horizon (matches nothing below)
+            running = (started[:, None] & (tt >= st)
+                       & (tt < end_eff))                           # [J, B]
+            phase = jnp.clip((tt - st) * u_phases // jnp.maximum(du, 1),
+                             0, u_phases - 1)
+            u_job = jnp.take_along_axis(w.util_levels, phase,
+                                        axis=1)                    # [J, B]
+            busy = jnp.where(
+                running, u_job * cores[:, None].astype(u_job.dtype), 0.0)
+            host_busy = jax.ops.segment_sum(
+                busy, seg, num_segments=max_hosts + 1)[:max_hosts]  # [H, B]
+            u_b = host_busy.T / jnp.maximum(cores_per_host, 1).astype(
+                host_busy.dtype)
+            started_by_t = started[:, None] & (tt >= st)           # [J, B]
+            queued = jnp.sum(
+                (submit[:, None] <= tt) & valid[:, None]
+                & jnp.logical_not(started_by_t), axis=0).astype(jnp.int32)
+            running_ct = jnp.sum(running, axis=0).astype(jnp.int32)
+            return u_b, queued, running_ct
 
-    # force_chunked_readout: a vmapping caller multiplies every intermediate
-    # by its batch size, which this function cannot see — the batch engine
-    # applies its own S-aware bound (see scenarios.run_scenarios).
-    if not force_chunked_readout and j * t_bins <= _READOUT_CHUNK_THRESHOLD:
-        u_th, queued, running_ct = readout_block(
-            jnp.arange(t_bins, dtype=jnp.int32))
-    else:
-        block = min(t_bins, _READOUT_BLOCK)
-        n_blocks = -(-t_bins // block)
-        tt_pad = jnp.full((n_blocks * block,), -1, jnp.int32)
-        tt_pad = tt_pad.at[:t_bins].set(jnp.arange(t_bins, dtype=jnp.int32))
-        u_b, q_b, r_b = jax.lax.map(
-            readout_block, tt_pad.reshape(n_blocks, block))
-        u_th = u_b.reshape(n_blocks * block, max_hosts)[:t_bins]
-        queued = q_b.reshape(-1)[:t_bins]
-        running_ct = r_b.reshape(-1)[:t_bins]
+        # force_chunked_readout: a vmapping caller multiplies every
+        # intermediate by its batch size, which this function cannot see —
+        # the batch engine applies its own S-aware bound (see
+        # scenarios.run_scenarios).
+        if (not force_chunked_readout
+                and j * t_bins <= _READOUT_CHUNK_THRESHOLD):
+            u_th, queued, running_ct = readout_block(
+                jnp.arange(t_bins, dtype=jnp.int32))
+        else:
+            block = min(t_bins, _READOUT_BLOCK)
+            n_blocks = -(-t_bins // block)
+            tt_pad = jnp.full((n_blocks * block,), -1, jnp.int32)
+            tt_pad = tt_pad.at[:t_bins].set(
+                jnp.arange(t_bins, dtype=jnp.int32))
+            u_b, q_b, r_b = jax.lax.map(
+                readout_block, tt_pad.reshape(n_blocks, block))
+            u_th = u_b.reshape(n_blocks * block, max_hosts)[:t_bins]
+            queued = q_b.reshape(-1)[:t_bins]
+            running_ct = r_b.reshape(-1)[:t_bins]
 
     return SimOutput(
         u_th=u_th,

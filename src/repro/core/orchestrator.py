@@ -24,12 +24,14 @@ Acceleration factor (paper §2.3): ratio between simulated and wall time.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from collections.abc import Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.calibrate import CalibrationSpec
 from repro.core.desim import PLACEMENT_POLICIES, Prediction, SimOutput, simulate_utilization
@@ -117,22 +119,56 @@ class WindowRecord:
     """Run metadata the orchestrator records per window (paper §2.3:
     'which outputs belong together').
 
-    ``sim_seconds`` times the whole fused ``twin_step`` (prediction *and*
-    calibration — they compile into one program since the pure-core
-    redesign); ``calib_seconds`` is kept for schema compatibility but is
-    always 0.0, as the fused program has no separable calibration phase.
+    ``started_at`` and ``sim_seconds`` are on the shell's :class:`Clock`:
+    ``sim_seconds`` times the fused ``twin_step`` (prediction and
+    calibration are one program) up to its prediction being ready.  They
+    stay for the benchmark's ``host_ms.twin``; where a window's time goes is
+    read from the profiler's ``opendt.window.*`` spans instead.
+
+    ``host_reads`` counts the device-to-host reads of a ``jax.Array`` the
+    window made, and ``host_read_bytes`` their size on the device.
     """
 
     window: int
     started_at: float
     sim_seconds: float
-    calib_seconds: float
     params: PowerParams
     prediction: Prediction
     mape: float | None = None        # filled when telemetry lands
     gco2: float | None = None        # window carbon (needs intensity trace)
     energy_cost: float | None = None  # window cost in $ (needs price trace)
     proposals: int = 0
+    host_reads: int = 0
+    host_read_bytes: int = 0
+
+
+class _HostReads:
+    """Counts a window's device-to-host reads of ``jax.Array`` values.
+
+    Each call does exactly the ``np.asarray``/``float`` it stands for; a
+    host value passes through uncounted.
+    """
+
+    def __init__(self):
+        self.count = 0
+        self.nbytes = 0
+
+    def _seen(self, x) -> None:
+        if isinstance(x, jax.Array):
+            self.count += 1
+            self.nbytes += x.nbytes
+
+    def array(self, x, dtype=None) -> np.ndarray:
+        self._seen(x)
+        return np.asarray(x, dtype)
+
+    def scalar(self, x) -> float:
+        self._seen(x)
+        return float(x)
+
+
+#: per-orchestrator id carried by its profiler spans (``twin=``)
+_TRACE_IDS = itertools.count()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,6 +257,7 @@ class Orchestrator:
                 "trace was supplied — pass ambient_c=[t_bins] deg C or use "
                 "a load-only PUE model (amb_coeff=0)")
         self.clock = clock or Clock()
+        self.trace_id = next(_TRACE_IDS)
         self.store = TelemetryStore(cfg.bins_per_window)
         self.gate = gate or HITLGate()
         self.records: list[WindowRecord] = []
@@ -282,14 +319,15 @@ class Orchestrator:
         workload or topology changes (what-if analysis does exactly that).
         """
         if self._sim is None:
-            self._sim = simulate_utilization(
-                self.workload,
-                num_hosts=self.dc.num_hosts,
-                cores_per_host=self.dc.cores_per_host,
-                t_bins=self.t_bins,
-                policy=self.policy,
-                backfill_depth=self.backfill_depth,
-            )
+            with TraceAnnotation("opendt.des", twin=self.trace_id):
+                self._sim = simulate_utilization(
+                    self.workload,
+                    num_hosts=self.dc.num_hosts,
+                    cores_per_host=self.dc.cores_per_host,
+                    t_bins=self.t_bins,
+                    policy=self.policy,
+                    backfill_depth=self.backfill_depth,
+                )
         return self._sim
 
     def invalidate(self) -> None:
@@ -309,123 +347,149 @@ class Orchestrator:
         """Execute one window: gather its inputs, advance the pure core one
         ``twin_step`` (predict S_k with params from C_{k-1}; score + calibrate
         C_k when telemetry has landed), then do the shell work — records,
-        float64 carbon bookkeeping, proposals, pacing."""
+        float64 carbon bookkeeping, proposals, pacing.
+
+        Profiler spans (``window=``, ``twin=`` as metadata): ``opendt.window``
+        around the whole window, and inside it ``opendt.window.inputs``,
+        ``.step`` (the call of the jitted step), ``.wait`` (until its
+        prediction is ready) and ``.readback``, which cover its body."""
+        span = dict(window=window, twin=self.trace_id)
+        with TraceAnnotation("opendt.window", **span):
+            return self._run_window(window, span)
+
+    def _run_window(self, window: int, span: dict) -> WindowRecord:
+        reads = _HostReads()
         t_start = self.clock.now()
-        sim = self._ensure_sim()
-        sl = self.window_slice(window)
+        with TraceAnnotation("opendt.window.inputs", **span):
+            sim = self._ensure_sim()
+            sl = self.window_slice(window)
 
-        # Telemetry for this window (produced asynchronously by the physical
-        # twin; in-loop experiments ingest it before calling run_window).
-        tw = self.store.get(window)
-        # Telemetry measured on a *different* topology (ingested before an
-        # apply_proposal resize) cannot score this twin — same not-landed
-        # treatment as missing telemetry, never a shape error inside jit.
-        if tw is not None and np.asarray(tw.u_th).shape[1] != self.dc.num_hosts:
-            tw = None
-        # window carbon: prefer *measured* intensity from telemetry extras
-        # over the configured forecast (same precedence as power itself).
-        ci_meas = (tw.extras.get(CARBON_INTENSITY_KEY)
-                   if tw is not None else None)
-        if ci_meas is not None and np.asarray(ci_meas).shape[0] != (sl.stop - sl.start):
-            ci_meas = None  # partially-clipped extras: fall back to forecast
-        if ci_meas is not None:
-            # same boundary rule as the forecast: a NaN/negative measured
-            # intensity (sensor glitch) must fail loudly, not flip the sign
-            # of the sustainability record.
-            ci_meas = validate_carbon_intensity(np.asarray(ci_meas))
+            # Telemetry for this window (produced asynchronously by the
+            # physical twin; in-loop experiments ingest it before calling
+            # run_window).
+            tw = self.store.get(window)
+            # Telemetry measured on a *different* topology (ingested before
+            # an apply_proposal resize) cannot score this twin — same
+            # not-landed treatment as missing telemetry, never a shape error
+            # inside jit.
+            if (tw is not None
+                    and reads.array(tw.u_th).shape[1] != self.dc.num_hosts):
+                tw = None
+            # window carbon: prefer *measured* intensity from telemetry
+            # extras over the configured forecast (same precedence as power
+            # itself).
+            ci_meas = (tw.extras.get(CARBON_INTENSITY_KEY)
+                       if tw is not None else None)
+            if (ci_meas is not None
+                    and reads.array(ci_meas).shape[0] != (sl.stop - sl.start)):
+                ci_meas = None  # partially-clipped extras: use the forecast
+            if ci_meas is not None:
+                # same boundary rule as the forecast: a NaN/negative measured
+                # intensity (sensor glitch) must fail loudly, not flip the
+                # sign of the sustainability record.
+                ci_meas = validate_carbon_intensity(reads.array(ci_meas))
 
-        # measured spot price / ambient from telemetry extras, same
-        # shape-check fallback and loud validation as carbon above.
-        w_bins = sl.stop - sl.start
-        pr_meas = tw.extras.get(PRICE_KEY) if tw is not None else None
-        if pr_meas is not None and np.asarray(pr_meas).shape[0] != w_bins:
-            pr_meas = None
-        if pr_meas is not None:
-            pr_meas = validate_price(np.asarray(pr_meas))
-        amb_meas = tw.extras.get(AMBIENT_KEY) if tw is not None else None
-        if amb_meas is not None and np.asarray(amb_meas).shape[0] != w_bins:
-            amb_meas = None
-        if amb_meas is not None:
-            amb_meas = validate_ambient(np.asarray(amb_meas))
+            # measured spot price / ambient from telemetry extras, same
+            # shape-check fallback and loud validation as carbon above.
+            w_bins = sl.stop - sl.start
+            pr_meas = tw.extras.get(PRICE_KEY) if tw is not None else None
+            if (pr_meas is not None
+                    and reads.array(pr_meas).shape[0] != w_bins):
+                pr_meas = None
+            if pr_meas is not None:
+                pr_meas = validate_price(reads.array(pr_meas))
+            amb_meas = (tw.extras.get(AMBIENT_KEY) if tw is not None
+                        else None)
+            if (amb_meas is not None
+                    and reads.array(amb_meas).shape[0] != w_bins):
+                amb_meas = None
+            if amb_meas is not None:
+                amb_meas = validate_ambient(reads.array(amb_meas))
 
-        ci_w = (jnp.asarray(self.carbon_intensity[sl], jnp.float32)
-                if self.carbon_intensity is not None else None)
-        # ambient feeds the *prediction* itself (PUE multiplies power), so a
-        # measured trace replaces the forecast slice before the step runs —
-        # a value-level swap, same shapes, no retrace.
-        amb_host = (amb_meas if amb_meas is not None
-                    else (self.ambient_c[sl]
-                          if self.ambient_c is not None else None))
-        amb_w = (jnp.asarray(amb_host, jnp.float32)
-                 if amb_host is not None else None)
-        pr_w = (jnp.asarray(self.price[sl], jnp.float32)
-                if self.price is not None else None)
-        telem = (make_telemetry(tw.u_th, tw.power_w) if tw is not None
-                 else empty_telemetry(self.cfg.bins_per_window,
-                                      self.dc.num_hosts))
+            ci_w = (jnp.asarray(self.carbon_intensity[sl], jnp.float32)
+                    if self.carbon_intensity is not None else None)
+            # ambient feeds the *prediction* itself (PUE multiplies power),
+            # so a measured trace replaces the forecast slice before the
+            # step runs — a value-level swap, same shapes, no retrace.
+            amb_host = (amb_meas if amb_meas is not None
+                        else (self.ambient_c[sl]
+                              if self.ambient_c is not None else None))
+            amb_w = (jnp.asarray(amb_host, jnp.float32)
+                     if amb_host is not None else None)
+            pr_w = (jnp.asarray(self.price[sl], jnp.float32)
+                    if self.price is not None else None)
+            telem = (make_telemetry(tw.u_th, tw.power_w) if tw is not None
+                     else empty_telemetry(self.cfg.bins_per_window,
+                                          self.dc.num_hosts))
+            # In resident-DES mode the step slices its own window from
+            # ``state.sim_u`` (u_th=None), so what it predicts from is
+            # whatever apply_proposal last seeded — not this shell's cache.
+            sim_slice = SimSlice(u_th=(None if self.cfg.sim_in_state
+                                       else sim.u_th[sl]),
+                                 carbon_intensity=ci_w, ambient_c=amb_w,
+                                 price=pr_w)
 
-        # All the math: one pure, jitted step on the twin core.  In
-        # resident-DES mode the step slices its own window from
-        # ``state.sim_u`` (u_th=None), so what it predicts from is whatever
-        # apply_proposal last seeded — not this shell's cache.
+        # All the math: one pure, jitted step on the twin core.
         t0 = self.clock.now()
-        self.state, out = twin_step_jit(
-            self.state, telem, SimSlice(u_th=(None if self.cfg.sim_in_state
-                                              else sim.u_th[sl]),
-                                        carbon_intensity=ci_w,
-                                        ambient_c=amb_w,
-                                        price=pr_w))
+        with TraceAnnotation("opendt.window.step", **span):
+            self.state, out = twin_step_jit(self.state, telem, sim_slice)
         pred = out.prediction
-        pred.power_w.block_until_ready()
+        with TraceAnnotation("opendt.window.wait", **span):
+            pred.power_w.block_until_ready()
         sim_seconds = self.clock.now() - t0
 
-        rec = WindowRecord(
-            window=window, started_at=t_start, sim_seconds=sim_seconds,
-            calib_seconds=0.0, params=out.params_used, prediction=pred,
-        )
-
-        # float64 sustainability record (host-side reporting precision).
-        if ci_meas is not None:
-            rec.gco2 = float(np.sum(
-                np.asarray(pred.energy_kwh, np.float64)
-                * np.asarray(ci_meas, np.float64)))
-        elif pred.gco2 is not None:
-            rec.gco2 = float(np.sum(np.asarray(pred.gco2, np.float64)))
-
-        # float64 energy-cost record: measured spot price wins over the
-        # forecast the traced lane priced with.
-        if pr_meas is not None:
-            rec.energy_cost = float(np.sum(
-                np.asarray(pred.energy_kwh, np.float64)
-                * np.asarray(pr_meas, np.float64)))
-        elif pred.energy_cost is not None:
-            rec.energy_cost = float(np.sum(
-                np.asarray(pred.energy_cost, np.float64)))
-
-        if tw is not None:
-            rec.mape = float(out.mape)
-
-            # SLO-aware proposals through the HITL gate.
-            props = propose_from_state(
-                window,
-                mape=rec.mape,
-                mean_util=float(np.mean(tw.u_th)),
-                queue_len=float(np.mean(np.asarray(sim.queue_len[sl]))),
-                power_w=float(np.mean(np.asarray(pred.power_w))),
-                power_cap_w=self.cfg.power_cap_w,
+        with TraceAnnotation("opendt.window.readback", **span):
+            rec = WindowRecord(
+                window=window, started_at=t_start, sim_seconds=sim_seconds,
+                params=out.params_used, prediction=pred,
             )
-            for p_ in props:
-                self.gate.submit(p_)
-            rec.proposals = len(props)
 
-        self.records.append(rec)
+            # float64 sustainability record (host-side reporting precision).
+            if ci_meas is not None:
+                rec.gco2 = float(np.sum(
+                    reads.array(pred.energy_kwh, np.float64)
+                    * reads.array(ci_meas, np.float64)))
+            elif pred.gco2 is not None:
+                rec.gco2 = float(np.sum(reads.array(pred.gco2, np.float64)))
 
-        # acceleration factor: live mode sleeps out the window's wall time.
-        if self.cfg.acceleration:
-            wall = self.cfg.bins_per_window * SAMPLE_SECONDS / self.cfg.acceleration
-            spent = self.clock.now() - t_start
-            if wall > spent:
-                self.clock.sleep(min(wall - spent, 1.0))  # capped for tests
+            # float64 energy-cost record: measured spot price wins over the
+            # forecast the traced lane priced with.
+            if pr_meas is not None:
+                rec.energy_cost = float(np.sum(
+                    reads.array(pred.energy_kwh, np.float64)
+                    * reads.array(pr_meas, np.float64)))
+            elif pred.energy_cost is not None:
+                rec.energy_cost = float(np.sum(
+                    reads.array(pred.energy_cost, np.float64)))
+
+            if tw is not None:
+                rec.mape = reads.scalar(out.mape)
+
+                # SLO-aware proposals through the HITL gate.
+                props = propose_from_state(
+                    window,
+                    mape=rec.mape,
+                    mean_util=reads.scalar(np.mean(tw.u_th)),
+                    queue_len=float(np.mean(reads.array(sim.queue_len[sl]))),
+                    power_w=float(np.mean(reads.array(pred.power_w))),
+                    power_cap_w=self.cfg.power_cap_w,
+                )
+                for p_ in props:
+                    self.gate.submit(p_)
+                rec.proposals = len(props)
+
+            rec.host_reads, rec.host_read_bytes = reads.count, reads.nbytes
+            self.records.append(rec)
+
+            # acceleration factor: live mode sleeps out the window's wall
+            # time.
+            if self.cfg.acceleration:
+                wall = (self.cfg.bins_per_window * SAMPLE_SECONDS
+                        / self.cfg.acceleration)
+                spent = self.clock.now() - t_start
+                if wall > spent:
+                    # capped for tests
+                    self.clock.sleep(min(wall - spent, 1.0))
         return rec
 
     # -- batched what-if analysis (paper Fig. 1, operator loop) --------------

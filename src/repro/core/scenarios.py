@@ -736,60 +736,62 @@ def _scenario_lanes(
             fail_end=fail_end if use_fail else None,
             fail_kill=fail_kill if use_fail else None,
         )
-        # effective per-bin cap: min(static facility cap, carbon-aware cap).
-        # The intensity trace is shared across scenarios (closure constant
-        # under the vmap); only the scalar cap parameters ride the S axis,
-        # so (caps x shifts x topologies) grids stay one program.
-        cap_t = cap_w
-        if carbon_intensity is not None:
-            cap_t = jnp.minimum(
-                cap_t,
-                jnp.maximum(carbon_base + carbon_slope * carbon_intensity,
-                            0.0))
-        if use_pallas:
-            # fused readout: failure windows become kernel operands (the
-            # online mask is rebuilt per tile from iota time ids) and the
-            # identity-PUE sentinels make the PUE multiply an exact no-op
-            # on lanes that leave the axis off.
-            rd = des_readout(
-                sim.u_th, backend=pallas_backend,
-                p_idle=params.p_idle, p_max=params.p_max, r=params.r,
-                mask=mask, cap_t=cap_t, intensity=carbon_intensity,
-                ambient=ambient_c, price=price, peak_tflops=peak,
-                pue_base=pue_base, pue_amb_coeff=pue_amb_coeff,
-                pue_amb_ref=pue_amb_ref, pue_load_coeff=pue_load_coeff,
-                fail_start=fail_start if use_fail else None,
-                fail_end=fail_end if use_fail else None,
-                fail_kill=fail_kill if use_fail else None,
-                model=model, precision=precision,
-                dt_seconds=SAMPLE_SECONDS)
-            pred = Prediction(
-                power_w=rd["power_w"], energy_kwh=rd["energy_kwh"],
-                tflops=rd["tflops"], utilization=rd["utilization"],
-                efficiency=rd["efficiency"],
-                gco2=None if carbon_intensity is None else rd["gco2"],
-                power_demand_w=rd["power_demand_w"],
-                pue=rd["pue"] if ss.pue_on else None,
-                energy_cost=None if price is None else rd["energy_cost"])
+        with jax.named_scope("opendt.power_readout"):
+            # effective per-bin cap: min(static facility cap, carbon-aware
+            # cap).  The intensity trace is shared across scenarios (closure
+            # constant under the vmap); only the scalar cap parameters ride
+            # the S axis, so (caps x shifts x topologies) grids stay one
+            # program.
+            cap_t = cap_w
+            if carbon_intensity is not None:
+                cap_t = jnp.minimum(
+                    cap_t,
+                    jnp.maximum(carbon_base + carbon_slope * carbon_intensity,
+                                0.0))
+            if use_pallas:
+                # fused readout: failure windows become kernel operands (the
+                # online mask is rebuilt per tile from iota time ids) and the
+                # identity-PUE sentinels make the PUE multiply an exact no-op
+                # on lanes that leave the axis off.
+                rd = des_readout(
+                    sim.u_th, backend=pallas_backend,
+                    p_idle=params.p_idle, p_max=params.p_max, r=params.r,
+                    mask=mask, cap_t=cap_t, intensity=carbon_intensity,
+                    ambient=ambient_c, price=price, peak_tflops=peak,
+                    pue_base=pue_base, pue_amb_coeff=pue_amb_coeff,
+                    pue_amb_ref=pue_amb_ref, pue_load_coeff=pue_load_coeff,
+                    fail_start=fail_start if use_fail else None,
+                    fail_end=fail_end if use_fail else None,
+                    fail_kill=fail_kill if use_fail else None,
+                    model=model, precision=precision,
+                    dt_seconds=SAMPLE_SECONDS)
+                pred = Prediction(
+                    power_w=rd["power_w"], energy_kwh=rd["energy_kwh"],
+                    tflops=rd["tflops"], utilization=rd["utilization"],
+                    efficiency=rd["efficiency"],
+                    gco2=None if carbon_intensity is None else rd["gco2"],
+                    power_demand_w=rd["power_demand_w"],
+                    pue=rd["pue"] if ss.pue_on else None,
+                    energy_cost=None if price is None else rd["energy_cost"])
+                return sim, pred
+            online_th = None
+            if use_fail:
+                # power-side availability: only *outage* hosts stop drawing
+                # power during their window (degraded hosts drain but burn)
+                tt = jnp.arange(t_bins, dtype=jnp.int32)[:, None]     # [T, 1]
+                offline = (fail_kill[None, :] & (tt >= fail_start[None, :])
+                           & (tt < fail_end[None, :]))                # [T, H]
+                online_th = mask[None, :] & jnp.logical_not(offline)
+            pue = None
+            if ss.pue_on:
+                from repro.traces.thermal import PUEParams
+                pue = PUEParams(base=pue_base, amb_coeff=pue_amb_coeff,
+                                amb_ref=pue_amb_ref, load_coeff=pue_load_coeff)
+            pred = _predict_masked(sim.u_th, params, mask, peak, model,
+                                   cap_t, carbon_intensity,
+                                   online_th=online_th, pue=pue,
+                                   ambient=ambient_c, price=price)
             return sim, pred
-        online_th = None
-        if use_fail:
-            # power-side availability: only *outage* hosts stop drawing
-            # power during their window (degraded hosts drain but burn)
-            tt = jnp.arange(t_bins, dtype=jnp.int32)[:, None]     # [T, 1]
-            offline = (fail_kill[None, :] & (tt >= fail_start[None, :])
-                       & (tt < fail_end[None, :]))                # [T, H]
-            online_th = mask[None, :] & jnp.logical_not(offline)
-        pue = None
-        if ss.pue_on:
-            from repro.traces.thermal import PUEParams
-            pue = PUEParams(base=pue_base, amb_coeff=pue_amb_coeff,
-                            amb_ref=pue_amb_ref, load_coeff=pue_load_coeff)
-        pred = _predict_masked(sim.u_th, params, mask, peak, model,
-                               cap_t, carbon_intensity,
-                               online_th=online_th, pue=pue,
-                               ambient=ambient_c, price=price)
-        return sim, pred
 
     return jax.vmap(one)(ss.workload, ss.host_mask_s, ss.cores_per_host,
                          ss.policy_id, ss.backfill_depth, ss.params,

@@ -382,13 +382,14 @@ def twin_step(state: TwinState, telemetry: TelemetrySlice,
         u_win = jax.lax.dynamic_slice_in_dim(
             state.sim_u, state.window * cfg.bins_per_window,
             cfg.bins_per_window, axis=0)
-    pred = predict_metrics(u_win, params, cfg.dc,
-                           model=cfg.power_model,
-                           carbon_intensity=sim_slice.carbon_intensity,
-                           ambient_c=sim_slice.ambient_c,
-                           price=sim_slice.price,
-                           pue=cfg.pue,
-                           backend=cfg.kernel_backend)
+    with jax.named_scope("opendt.twin_predict"):
+        pred = predict_metrics(u_win, params, cfg.dc,
+                               model=cfg.power_model,
+                               carbon_intensity=sim_slice.carbon_intensity,
+                               ambient_c=sim_slice.ambient_c,
+                               price=sim_slice.price,
+                               pue=cfg.pue,
+                               backend=cfg.kernel_backend)
 
     # Scoring: window MAPE against measured power (NaN without telemetry).
     valid = telemetry.valid
@@ -406,20 +407,22 @@ def twin_step(state: TwinState, telemetry: TelemetrySlice,
     calib_mape = jnp.asarray(jnp.nan, jnp.float32)
     if cfg.calibrate:
         # C_k — masked history push + grid search for S_{k+1}.
-        hist_u = jnp.where(valid, _push(state.hist_u, telemetry.u_th,
-                                        state.hist_n), state.hist_u)
-        hist_p = jnp.where(valid, _push(state.hist_p, telemetry.power_w,
-                                        state.hist_n), state.hist_p)
-        hist_n = jnp.where(valid,
-                           jnp.minimum(state.hist_n + 1,
-                                       cfg.history_windows), state.hist_n)
-        k, tw, h = hist_u.shape
-        new_params, best_mape = calibrate_traced(
-            hist_u.reshape(k * tw, h), hist_p.reshape(k * tw),
-            state.cand, cfg.calibration, state.base_params,
-            backend=cfg.kernel_backend)
-        params_next = _where_tree(valid, new_params, params)
-        calib_mape = jnp.where(valid, best_mape, jnp.nan)
+        with jax.named_scope("opendt.twin_calibrate"):
+            hist_u = jnp.where(valid, _push(state.hist_u, telemetry.u_th,
+                                            state.hist_n), state.hist_u)
+            hist_p = jnp.where(valid, _push(state.hist_p, telemetry.power_w,
+                                            state.hist_n), state.hist_p)
+            hist_n = jnp.where(valid,
+                               jnp.minimum(state.hist_n + 1,
+                                           cfg.history_windows),
+                               state.hist_n)
+            k, tw, h = hist_u.shape
+            new_params, best_mape = calibrate_traced(
+                hist_u.reshape(k * tw, h), hist_p.reshape(k * tw),
+                state.cand, cfg.calibration, state.base_params,
+                backend=cfg.kernel_backend)
+            params_next = _where_tree(valid, new_params, params)
+            calib_mape = jnp.where(valid, best_mape, jnp.nan)
 
     new_state = TwinState(
         params=params_next,

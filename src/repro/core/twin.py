@@ -21,6 +21,7 @@ from typing import Callable, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.desim import simulate_utilization
 from repro.core.feedback import HITLGate, Proposal
@@ -65,7 +66,13 @@ class TwinRunResult:
 
 
 class DigitalTwin:
-    """OpenDT's outer loop."""
+    """OpenDT's outer loop.
+
+    Profiler spans: ``opendt.twin.init`` (construction), ``opendt.ingest``
+    (per window: the telemetry source and the store's ingest) and
+    ``opendt.twin.finish`` (the run's reports), beside the orchestrator's
+    ``opendt.window`` spans.
+    """
 
     def __init__(
         self,
@@ -76,10 +83,11 @@ class DigitalTwin:
         base_params: PowerParams = PowerParams(),
         hitl_policy: Callable[[Proposal], bool | None] | None = None,
     ):
-        self.gate = HITLGate(policy=hitl_policy)
-        self.orchestrator = Orchestrator(
-            workload, dc, t_bins, cfg, base_params, gate=self.gate,
-        )
+        with TraceAnnotation("opendt.twin.init"):
+            self.gate = HITLGate(policy=hitl_policy)
+            self.orchestrator = Orchestrator(
+                workload, dc, t_bins, cfg, base_params, gate=self.gate,
+            )
 
     def run(
         self,
@@ -91,18 +99,21 @@ class DigitalTwin:
         n = num_windows if num_windows is not None else orch.num_windows
         approved: list[Proposal] = []
         for w in range(n):
-            tw = telemetry_source(w, orch.cfg.bins_per_window)
-            orch.store.ingest(tw)
+            with TraceAnnotation("opendt.ingest", window=w,
+                                 twin=orch.trace_id):
+                tw = telemetry_source(w, orch.cfg.bins_per_window)
+                orch.store.ingest(tw)
             orch.run_window(w)
             approved.extend(self.gate.drain())
-        return TwinRunResult(
-            records=orch.records,
-            overall_mape=orch.overall_mape(),
-            per_window_mape=orch.per_window_mape(),
-            slo_reports=orch.monitor.report(),
-            under_estimation_fraction=orch.bias.under_fraction,
-            approved_proposals=approved,
-        )
+        with TraceAnnotation("opendt.twin.finish", twin=orch.trace_id):
+            return TwinRunResult(
+                records=orch.records,
+                overall_mape=orch.overall_mape(),
+                per_window_mape=orch.per_window_mape(),
+                slo_reports=orch.monitor.report(),
+                under_estimation_fraction=orch.bias.under_fraction,
+                approved_proposals=approved,
+            )
 
 
 # -- fleet twinning: vmap(twin_step) over independent datacenters -------------

@@ -132,6 +132,7 @@ def calib_mape_grid_pallas(
         out_specs=pl.BlockSpec((tb_c, 1), lambda ci, ti: (ci, 0)),
         out_shape=jax.ShapeDtypeStruct((cp, 1), jnp.float32),
         interpret=interpret,
+        name="calib_mape",
     )(u, real, pi, pm, rr)
     # normalization matches power.mape: mean over the *nonzero-real* bins
     # (zero-real bins carry no meaningful percentage error and were masked

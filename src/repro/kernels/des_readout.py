@@ -257,6 +257,7 @@ def des_readout_pallas(
         out_specs=[col_spec] * len(READOUT_FIELDS),
         out_shape=[shape_t] * len(READOUT_FIELDS),
         interpret=interpret,
+        name="des_readout",
     )(*operands)
     return {k: v[:t, 0] for k, v in zip(READOUT_FIELDS, outs)}
 

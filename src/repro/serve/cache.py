@@ -28,6 +28,7 @@ import collections
 import hashlib
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import codec
 from repro.core.desim import Prediction
@@ -138,10 +139,11 @@ class ResultCache:
         return blob
 
     def put(self, key: tuple, blob: bytes) -> None:
-        self._entries[key] = blob
-        self._entries.move_to_end(key)
-        while len(self._entries) > self.capacity:
-            self._entries.popitem(last=False)
+        with TraceAnnotation("opendt.serve.cache_put"):
+            self._entries[key] = blob
+            self._entries.move_to_end(key)
+            while len(self._entries) > self.capacity:
+                self._entries.popitem(last=False)
 
     def __len__(self) -> int:
         return len(self._entries)

@@ -42,6 +42,7 @@ import threading
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.orchestrator import Clock
 from repro.core.power import PowerParams
@@ -303,34 +304,39 @@ class TwinService:
             self.stats.windows_served += 1
 
     def _dispatch(self, ready: "dict[str, tuple[WindowEvent, tuple]]") -> None:
-        by_lane = {self._lanes.lane(t): ev for t, (ev, _) in ready.items()}
-        telem, sim, active = build_fleet_inputs(
-            by_lane, self.cfg.lanes, self.cfg.twin, self.cfg.columns)
-        new_fleet, outs = fleet_step_masked(
-            self._fleet, telem, sim, active,
-            shard=self.cfg.shard, mesh=self.cfg.mesh)
-        entries = []
-        for tenant, (ev, key) in ready.items():
-            lane = self._lanes.lane(tenant)
-            # slice the successor lane state NOW: these reads are enqueued
-            # before new_fleet is donated into the next dispatch, so the
-            # slices are safe independent buffers
-            entries.append((tenant, lane, key,
-                            index_twin_state(new_fleet, lane)))
-        self._fleet = new_fleet
-        self._inflight.append(_Inflight(outs=outs, entries=entries))
-        self.stats.batches += 1
-        self.stats.lanes_stepped += len(ready)
+        with TraceAnnotation("opendt.serve.dispatch", lanes=len(ready)):
+            by_lane = {self._lanes.lane(t): ev
+                       for t, (ev, _) in ready.items()}
+            telem, sim, active = build_fleet_inputs(
+                by_lane, self.cfg.lanes, self.cfg.twin, self.cfg.columns)
+            new_fleet, outs = fleet_step_masked(
+                self._fleet, telem, sim, active,
+                shard=self.cfg.shard, mesh=self.cfg.mesh)
+            entries = []
+            for tenant, (ev, key) in ready.items():
+                lane = self._lanes.lane(tenant)
+                # slice the successor lane state NOW: these reads are
+                # enqueued before new_fleet is donated into the next
+                # dispatch, so the slices are safe independent buffers
+                entries.append((tenant, lane, key,
+                                index_twin_state(new_fleet, lane)))
+            self._fleet = new_fleet
+            self._inflight.append(_Inflight(outs=outs, entries=entries))
+            self.stats.batches += 1
+            self.stats.lanes_stepped += len(ready)
 
     def _harvest_one(self) -> None:
         batch = self._inflight.popleft()
-        for tenant, lane, key, succ in batch.entries:
-            out = jax.tree.map(lambda x: np.asarray(x[lane]), batch.outs)
-            if self.cache is not None:
-                self.cache.put(key, encode_result(out, succ))
-            self.stats.windows_computed += 1
-            self._stage(WindowResult(tenant=tenant, window=int(out.window),
-                                     output=out, cached=False))
+        with TraceAnnotation("opendt.serve.harvest",
+                             lanes=len(batch.entries)):
+            for tenant, lane, key, succ in batch.entries:
+                out = jax.tree.map(lambda x: np.asarray(x[lane]), batch.outs)
+                if self.cache is not None:
+                    self.cache.put(key, encode_result(out, succ))
+                self.stats.windows_computed += 1
+                self._stage(WindowResult(tenant=tenant,
+                                         window=int(out.window),
+                                         output=out, cached=False))
 
     def _step_once(self) -> bool:
         """One scheduling round; True when any work happened."""
