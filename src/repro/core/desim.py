@@ -171,6 +171,35 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def _phase_lookup(util_levels: Array, du: Array):
+    """``level_at(x)``: each job's utilization level ``x`` bins after its
+    start, for ``util_levels [J, P]`` and durations ``du [J, 1]`` (>= 1).
+
+    A job is in phase ``clip(x * P // du, 0, P - 1)``.  For integer ``x``
+    and ``du >= 1`` that phase is ``>= p`` exactly when
+    ``x >= ceil(p * du / P)``, negative ``x`` (the ``tt = -1`` padding)
+    included, so the per-job thresholds ``[J, P-1]`` are computed once and
+    the lookup is a static chain of ``P - 1`` compares and selects: vector
+    work that returns the same f32 element a per-element gather would
+    (and a batched gather runs serially on a TPU).  Its cost grows with
+    the static ``P`` (8 for SURF-22); the chain stays cheaper than the
+    gather until ``P`` is in the hundreds, so every caller shares it.
+    """
+    n_p = util_levels.shape[-1]
+    p_up = jnp.arange(1, n_p, dtype=jnp.int32)                      # [P-1]
+    # ceil(p * du / P) as p * (du // P) + ceil(p * (du % P) / P): no
+    # product can overflow int32
+    thresh = p_up * (du // n_p) + (p_up * (du % n_p) + n_p - 1) // n_p
+
+    def level_at(x):
+        u = util_levels[:, :1]
+        for p in range(1, n_p):
+            u = jnp.where(x >= thresh[:, p - 1:p], util_levels[:, p:p + 1], u)
+        return jnp.broadcast_to(u, x.shape)
+
+    return level_at
+
+
 def simulate_utilization_masked(
     w: Workload,
     host_mask: Array,
@@ -431,7 +460,6 @@ def simulate_utilization_masked(
         # intermediates stay bounded at O(jobs * block) per scenario (under
         # the scenario vmap the full-horizon version would materialize
         # [S, jobs, bins] arrays).
-        u_phases = w.num_phases
         started = job_start >= 0                           # [J]
         st = job_start[:, None]                            # [J, 1]
         du = dur[:, None]
@@ -447,15 +475,13 @@ def simulate_utilization_masked(
             end_eff = jnp.where(killed_j, fs_j, st + du)
         else:
             end_eff = st + du
+        level_at = _phase_lookup(w.util_levels, du)
 
         def readout_block(tt):
             # tt [B] with -1 padding past the horizon (matches nothing below)
             running = (started[:, None] & (tt >= st)
                        & (tt < end_eff))                           # [J, B]
-            phase = jnp.clip((tt - st) * u_phases // jnp.maximum(du, 1),
-                             0, u_phases - 1)
-            u_job = jnp.take_along_axis(w.util_levels, phase,
-                                        axis=1)                    # [J, B]
+            u_job = level_at(tt - st)                              # [J, B]
             busy = jnp.where(
                 running, u_job * cores[:, None].astype(u_job.dtype), 0.0)
             host_busy = jax.ops.segment_sum(
