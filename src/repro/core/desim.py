@@ -123,16 +123,12 @@ def _hash_scores(host_idx: Array, t: Array, salt: Array) -> Array:
     return (x & jnp.uint32(0x7FFFFF)).astype(jnp.int32)
 
 
-def _policy_host(free: Array, fits: Array, policy_id: Array,
-                 t: Array, salt: Array, max_hosts: int) -> Array:
-    """Branchless host selection: argmax of a policy-indexed score.
+def _policy_score(free: Array, policy_id: Array, t: Array, salt: Array,
+                  max_hosts: int) -> Array:
+    """Per-host int32 score of the *traced* ``policy_id`` (all >= 0).
 
-    Builds the [4, max_hosts] score table (all int32, all >= 0 so the -1
-    "does not fit" sentinel always loses), gathers the row for the *traced*
-    ``policy_id``, and takes the argmax over fitting hosts.  Ties break to
-    the lowest host index (argmax returns the first maximum), which makes
-    WORST_FIT bit-identical to the pre-policy-kernel scheduler
-    ``argmax(where(fits, free, -1))``.
+    Builds the [4, max_hosts] score table and gathers the policy's row, so
+    the four policies share one program.
     """
     idx = jnp.arange(max_hosts, dtype=jnp.int32)
     scores = jnp.stack([
@@ -141,7 +137,19 @@ def _policy_host(free: Array, fits: Array, policy_id: Array,
         free,                                               # WORST_FIT
         _hash_scores(idx, t, salt),                         # RANDOM_FIT
     ])
-    score = scores[jnp.clip(policy_id, 0, len(PLACEMENT_POLICIES) - 1)]
+    return scores[jnp.clip(policy_id, 0, len(PLACEMENT_POLICIES) - 1)]
+
+
+def _policy_host(free: Array, fits: Array, policy_id: Array,
+                 t: Array, salt: Array, max_hosts: int) -> Array:
+    """Branchless host selection: argmax of a policy-indexed score.
+
+    The score (:func:`_policy_score`) is >= 0, so the -1 "does not fit"
+    sentinel always loses.  Ties break to the lowest host index (argmax
+    returns the first maximum), which makes WORST_FIT bit-identical to the
+    pre-policy-kernel scheduler ``argmax(where(fits, free, -1))``.
+    """
+    score = _policy_score(free, policy_id, t, salt, max_hosts)
     return jnp.argmax(jnp.where(fits, score, -1))
 
 
@@ -154,7 +162,13 @@ class SimOutput:
       queue_len: ``[T]`` jobs submitted but not yet started.
       running: ``[T]`` jobs running.
       job_start: ``[J]`` assigned start bin (-1 if never started).
-      job_host: ``[J]`` assigned host (-1 if never started).
+      job_host: ``[J]`` assigned host (-1 if never started); a gang's
+        first host.
+      job_hosts: ``[J, max_gang]`` every host of each job, ``-1``-padded,
+        or ``None`` when gangs are compiled out (``max_gang = 1``).
+      gang_blocked_bins: scalar int32, bins whose placement stopped at a
+        gang head that found too few whole free servers; ``None`` when
+        gangs are compiled out.
     """
 
     u_th: Array
@@ -162,11 +176,14 @@ class SimOutput:
     running: Array
     job_start: Array
     job_host: Array
+    job_hosts: Array | None = None
+    gang_blocked_bins: Array | None = None
 
 
 jax.tree_util.register_pytree_node(
     SimOutput,
-    lambda s: ((s.u_th, s.queue_len, s.running, s.job_start, s.job_host), None),
+    lambda s: ((s.u_th, s.queue_len, s.running, s.job_start, s.job_host,
+                s.job_hosts, s.gang_blocked_bins), None),
     lambda _, c: SimOutput(*c),
 )
 
@@ -215,11 +232,14 @@ def simulate_utilization_masked(
     fail_start: "Array | None" = None,
     fail_end: "Array | None" = None,
     fail_kill: "Array | None" = None,
+    max_gang: int = 1,
 ) -> SimOutput:
     """Masked-host-axis DES core (trace-level; callers jit/vmap it).
 
     The host axis is padded to a static ``max_hosts``; ``host_mask [max_hosts]``
-    marks the active hosts and ``cores_per_host`` is a *traced* int32 scalar.
+    marks the active hosts and ``cores_per_host`` is a *traced* int32 scalar,
+    or a ``[max_hosts]`` row of per-host capacities for a fleet of mixed
+    server sizes (units: cores, or GPUs).
     Inactive hosts start with 0 free cores and are excluded from placement, so
     they never run jobs and report 0 utilization.  Because every argument that
     varies between what-if candidates (mask, cores, workload, **policy**) is a
@@ -259,6 +279,20 @@ def simulate_utilization_masked(
     arrays is *structural* (a Python-level ``is not None``), so the
     default program is unchanged when the axis is off.
 
+    Gang jobs (static ``max_gang > 1``): a job asking for more than
+    ``unit`` units, the largest active host's capacity, is gang-scheduled.
+    It takes ``n = ceil(cores / unit)`` whole free ``unit``-hosts that are
+    online, all at once or not at all: the top ``n`` of the policy's score
+    (ties to the lowest index; :func:`jax.lax.top_k`), each host held whole
+    and running ``cores / n`` units of the job.  A job wider than
+    ``max_gang`` hosts never fits, as a single-host job wider than every
+    host never does.  Backfill candidates may be gangs.  If any host of a
+    running gang has an outage (``fail_kill``), the whole job dies at the
+    first outage start: its healthy hosts come back at that bin, the
+    outage host at its ``fail_end``.  ``max_gang = 1`` compiles the gang
+    machinery out, as ``max_backfill = 0`` does backfill, and then
+    ``job_hosts`` and ``gang_blocked_bins`` are ``None``.
+
     Placement (the event-driven part) is a bounded policy-kernel loop inside
     the scan body; utilization accumulation is a segment-sum scatter over
     host assignments.  Utilization is *independent of power-model
@@ -268,6 +302,9 @@ def simulate_utilization_masked(
     if not 0 <= max_backfill <= 31:
         # the skip bitmask is uint32 and bit max_backfill must be addressable
         raise ValueError(f"max_backfill must be in [0, 31], got {max_backfill}")
+    if max_gang < 1:
+        raise ValueError(f"max_gang must be >= 1, got {max_gang}")
+    gang = max_gang > 1
     j = w.num_jobs
     host_mask = jnp.asarray(host_mask, jnp.bool_)
     cores_per_host = jnp.asarray(cores_per_host, jnp.int32)
@@ -289,6 +326,14 @@ def simulate_utilization_masked(
     dur = jnp.maximum(w.duration_bins, 1)
     cores = w.cores
     valid = w.valid
+    if gang:
+        # the gang unit: the largest active host.  A job above it needs
+        # n_host whole unit-hosts; every other job one host.
+        unit = jnp.maximum(
+            jnp.max(jnp.where(host_mask, cores_per_host, 0)), 1)
+        is_gang = cores > unit                                      # [J]
+        n_host = jnp.where(is_gang, (cores + unit - 1) // unit, 1)  # [J]
+        gang_ok = n_host <= max_gang
 
     # The scan carries *placement state only*: which job starts where/when,
     # free cores, a [t_bins+1, max_hosts] core-release table written at
@@ -309,6 +354,15 @@ def simulate_utilization_masked(
         skip=jnp.asarray(0, jnp.uint32),
         release=jnp.zeros((t_bins + 1, max_hosts), jnp.int32),
     )
+    if gang:
+        # a gang's hosts replace the one host per job.  A gang holds each
+        # of its hosts whole, so a host has at most one gang release
+        # pending, kept per host (the bin its units come back, -1 = none)
+        # and not in the release table.
+        del init["job_host"]
+        init["job_hosts"] = jnp.full((j, max_gang), -1, jnp.int32)
+        init["gang_back"] = jnp.full((max_hosts,), -1, jnp.int32)
+        init["gang_blocked"] = jnp.asarray(0, jnp.int32)
 
     def head_ready(next_job, blocked, t):
         """Is the FCFS head job submittable at bin t (and are we unblocked)?"""
@@ -335,7 +389,7 @@ def simulate_utilization_masked(
     # sets `blocked` (ending the bin), so the loop is bounded by
     # max_starts_per_bin placements.
     def place_one(carry):
-        free, next_job, skip, blocked, t, n, buf_jid, buf_host = carry
+        free, next_job, skip, blocked, t, n, buf_jid, buf_host = carry[:8]
         # failed hosts (outage or drain) accept no new placements during
         # their window; sentinel starts make this the plain mask.
         if fail_start is not None:
@@ -347,6 +401,18 @@ def simulate_utilization_masked(
         # re-checked inside the body: finished vmap lanes degrade to no-ops.
         eligible = head_ready(next_job, blocked, t)
         head_fits = jnp.any((free >= cores[jid_h]) & online)
+        if gang:
+            # whole free unit-hosts: free == capacity == unit
+            whole = (free >= unit) & online
+            n_whole = jnp.sum(whole.astype(jnp.int32))
+
+            def fits_job(jid, fits_one):
+                """A gang needs enough whole hosts; a job one host."""
+                return jnp.where(is_gang[jid],
+                                 gang_ok[jid] & (n_host[jid] <= n_whole),
+                                 fits_one)
+
+            head_fits = fits_job(jid_h, head_fits)
         place_head = eligible & head_fits
 
         if max_backfill > 0:
@@ -360,7 +426,10 @@ def simulate_utilization_masked(
                       & jnp.logical_not(already) & (d_off <= depth))
             fits_c = ((free[None, :] >= cores[jid_c][:, None])
                       & online[None, :])                             # [K, H]
-            startable = elig_c & jnp.any(fits_c, axis=1)             # [K]
+            fits_any = jnp.any(fits_c, axis=1)                       # [K]
+            if gang:
+                fits_any = fits_job(jid_c, fits_any)
+            startable = elig_c & fits_any                            # [K]
             any_bf = jnp.any(startable)
             d_sel = jnp.argmax(startable)        # first startable offset - 1
             place_bf = eligible & jnp.logical_not(head_fits) & any_bf
@@ -371,12 +440,33 @@ def simulate_utilization_masked(
 
         need = cores[jid]
         fits = (free >= need) & online
-        host = _policy_host(free, fits, policy_id, t,
-                            jnp.asarray(n, jnp.int32), max_hosts)
         do_place = place_head | place_bf
-        free = free.at[host].add(jnp.where(do_place, -need, 0))
+        if gang:
+            with jax.named_scope("opendt.gang_select"):
+                # the top n_host of the policy's score among whole hosts
+                # (one host among fitting ones for a plain job); top_k
+                # breaks ties to the lowest index, as argmax does.
+                g = is_gang[jid]
+                cand = jnp.where(g, whole, fits)
+                score = _policy_score(free, policy_id, t,
+                                      jnp.asarray(n, jnp.int32), max_hosts)
+                _, top = jax.lax.top_k(jnp.where(cand, score, -1), max_gang)
+                take = (jnp.arange(max_gang) < n_host[jid]) & do_place
+                hosts = jnp.where(take, top.astype(jnp.int32), -1)
+                # the chosen hosts as a mask: vector selects, no scatter
+                sel = jnp.any(hosts[:, None] == jnp.arange(
+                    max_hosts, dtype=jnp.int32)[None, :], axis=0)    # [H]
+                free = free - jnp.where(sel, jnp.where(g, unit, need), 0)
+                gang_back = jnp.where(sel & g,
+                                      _gang_back(sel, t, t + dur[jid]),
+                                      carry[8])
+            buf_host = buf_host.at[n].set(hosts)
+        else:
+            host = _policy_host(free, fits, policy_id, t,
+                                jnp.asarray(n, jnp.int32), max_hosts)
+            free = free.at[host].add(jnp.where(do_place, -need, 0))
+            buf_host = buf_host.at[n].set(host)
         buf_jid = buf_jid.at[n].set(jnp.where(do_place, jid, j))
-        buf_host = buf_host.at[n].set(host)
 
         if max_backfill > 0:
             # head placed: advance past it and any backfilled successors.
@@ -395,35 +485,70 @@ def simulate_utilization_masked(
             blocked = blocked | (eligible & jnp.logical_not(head_fits))
 
         return (free, next_job, skip, blocked, t,
-                n + do_place.astype(jnp.int32), buf_jid, buf_host)
+                n + do_place.astype(jnp.int32), buf_jid,
+                buf_host) + ((gang_back,) if gang else ())
+
+    def _gang_back(sel, t, end_nom):
+        """Per host, the bin a gang placed at ``t`` on the hosts ``sel``
+        gives them back: its end, or under the gang kill rule (the job
+        dies at the first outage start among its hosts that falls inside
+        its run) that outage's start, and the outage host's own
+        ``fail_end`` for that host.  Clipped to the horizon."""
+        back = jnp.broadcast_to(end_nom, sel.shape)
+        if fail_start is not None:
+            kill = sel & fail_kill & (t < fail_start) & (end_nom > fail_start)
+            kt = jnp.min(jnp.where(kill, fail_start,
+                                   jnp.iinfo(jnp.int32).max))
+            back = jnp.where(jnp.any(kill), jnp.where(
+                kill & (fail_start == kt), fail_end, kt), back)
+        return jnp.minimum(back, t_bins)
 
     def keep_placing(carry):
-        free, next_job, skip, blocked, t, n, buf_jid, buf_host = carry
+        next_job, blocked, t, n = carry[1], carry[3], carry[4], carry[5]
         return head_ready(next_job, blocked, t) & (n < max_starts_per_bin)
 
     def step(state, t):
         # 1) completions: cores banked in the release table at placement time.
         free = state["free"] + state["release"][t]
+        if gang:
+            free = free + jnp.where(state["gang_back"] == t, unit, 0)
 
         # 2) placement, bounded attempts with early exit: most bins place far
         # fewer than max_starts_per_bin jobs, and the while_loop stops as
         # soon as the head job is unsubmittable or the bin is blocked instead
         # of burning the remaining attempts on no-op iterations.
         buf_jid = jnp.full((max_starts_per_bin,), j, jnp.int32)
-        buf_host = jnp.zeros((max_starts_per_bin,), jnp.int32)
-        free, next_job, skip, _, _, _, buf_jid, buf_host = jax.lax.while_loop(
+        buf_host = (jnp.full((max_starts_per_bin, max_gang), -1, jnp.int32)
+                    if gang else jnp.zeros((max_starts_per_bin,), jnp.int32))
+        (free, next_job, skip, blocked, _, _, buf_jid,
+         buf_host, *gang_back) = jax.lax.while_loop(
             keep_placing, place_one,
             (free, state["next_job"], state["skip"], jnp.asarray(False), t,
-             jnp.asarray(0, jnp.int32), buf_jid, buf_host),
+             jnp.asarray(0, jnp.int32), buf_jid, buf_host)
+            + ((state["gang_back"],) if gang else ()),
         )
 
         # 3) apply this bin's placements (unused buffer slots hold the
         # out-of-bounds sentinel job id j and are dropped by the scatter).
         jj = jnp.minimum(buf_jid, j - 1)
         placed = buf_jid < j
-        job_host = state["job_host"].at[buf_jid].set(buf_host, mode="drop")
         job_start = state["job_start"].at[buf_jid].set(t, mode="drop")
         end_nom = t + dur[jj]
+        if gang:
+            # plain jobs go to the release table by their one host, as
+            # below; gangs were banked in gang_back while placed
+            head = jnp.minimum(next_job, j - 1)
+            gang_state = dict(
+                job_hosts=state["job_hosts"].at[buf_jid].set(
+                    buf_host, mode="drop"),
+                gang_back=gang_back[0],
+                gang_blocked=state["gang_blocked"]
+                + (blocked & is_gang[head]).astype(jnp.int32))
+            placed = placed & jnp.logical_not(is_gang[jj])
+            buf_host = jnp.maximum(buf_host[:, 0], 0)
+        else:
+            job_host = state["job_host"].at[buf_jid].set(buf_host,
+                                                         mode="drop")
         if fail_start is not None:
             # kill rule, applied at placement time: a job landing on a
             # kill-host *before* its outage and running into it dies at
@@ -439,8 +564,9 @@ def simulate_utilization_masked(
         release = state["release"].at[end_bin, buf_host].add(
             jnp.where(placed, cores[jj], 0))
 
-        new_state = dict(free=free, job_host=job_host, job_start=job_start,
-                         next_job=next_job, skip=skip, release=release)
+        new_state = dict(free=free, job_start=job_start, next_job=next_job,
+                         skip=skip, release=release)
+        new_state.update(gang_state if gang else dict(job_host=job_host))
         return new_state, None
 
     # named scopes: stable names for the device time of the placement
@@ -449,7 +575,9 @@ def simulate_utilization_masked(
         state, _ = jax.lax.scan(
             step, init, jnp.arange(t_bins, dtype=jnp.int32)
         )
-    job_start, job_host = state["job_start"], state["job_host"]
+    job_start = state["job_start"]
+    job_hosts = state["job_hosts"] if gang else None
+    job_host = job_hosts[:, 0] if gang else state["job_host"]
 
     # -- vectorized post-scan read-out ---------------------------------------
     with jax.named_scope("opendt.des_expand"):
@@ -464,7 +592,24 @@ def simulate_utilization_masked(
         st = job_start[:, None]                            # [J, 1]
         du = dur[:, None]
         seg = jnp.where(started, job_host, max_hosts)      # sentinel bucket
-        if fail_start is not None:
+        units = cores[:, None]
+        if gang:
+            # a gang's units are spread evenly over its hosts
+            units = (cores.astype(jnp.float32)
+                     / n_host.astype(jnp.float32))[:, None]
+        if gang and fail_start is not None:
+            # the gang kill rule: the job stops at the first outage start
+            # among its hosts that falls inside its run
+            on = (job_hosts >= 0) & started[:, None]               # [J, G]
+            fs_g = fail_start[jnp.where(on, job_hosts, 0)]
+            kill_g = (fail_kill[jnp.where(on, job_hosts, 0)] & on
+                      & (st < fs_g) & (st + du > fs_g))
+            end_eff = jnp.where(
+                jnp.any(kill_g, axis=1, keepdims=True),
+                jnp.min(jnp.where(kill_g, fs_g, jnp.iinfo(jnp.int32).max),
+                        axis=1, keepdims=True),
+                st + du)
+        elif fail_start is not None:
             # per-job effective end: killed jobs (placed pre-outage on a
             # kill-host, overlapping its window) stop at fail_start.
             # Mirrors the release-table kill rule above.
@@ -483,9 +628,18 @@ def simulate_utilization_masked(
                        & (tt < end_eff))                           # [J, B]
             u_job = level_at(tt - st)                              # [J, B]
             busy = jnp.where(
-                running, u_job * cores[:, None].astype(u_job.dtype), 0.0)
+                running, u_job * units.astype(u_job.dtype), 0.0)
             host_busy = jax.ops.segment_sum(
                 busy, seg, num_segments=max_hosts + 1)[:max_hosts]  # [H, B]
+            if gang:
+                with jax.named_scope("opendt.gang_expand"):
+                    # a gang's further hosts get the same busy rows
+                    for k in range(1, max_gang):
+                        seg_k = jnp.where(started & (job_hosts[:, k] >= 0),
+                                          job_hosts[:, k], max_hosts)
+                        host_busy = host_busy + jax.ops.segment_sum(
+                            busy, seg_k,
+                            num_segments=max_hosts + 1)[:max_hosts]
             u_b = host_busy.T / jnp.maximum(cores_per_host, 1).astype(
                 host_busy.dtype)
             started_by_t = started[:, None] & (tt >= st)           # [J, B]
@@ -521,41 +675,52 @@ def simulate_utilization_masked(
         running=running_ct,
         job_start=job_start,
         job_host=job_host,
+        job_hosts=job_hosts,
+        gang_blocked_bins=state["gang_blocked"] if gang else None,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("num_hosts", "cores_per_host",
                                              "t_bins", "max_starts_per_bin",
-                                             "policy", "backfill_depth"))
+                                             "policy", "backfill_depth",
+                                             "max_gang"))
 def simulate_utilization(
     w: Workload,
     *,
     num_hosts: int,
-    cores_per_host: int,
+    cores_per_host: "int | tuple[int, ...]",
     t_bins: int,
     max_starts_per_bin: int = 64,
     policy: "str | int | None" = None,
     backfill_depth: int = 0,
+    max_gang: int = 1,
 ) -> SimOutput:
     """Run the vectorized DES and return the utilization field.
 
     Single-topology entry point: the masked core with every host active.
     ``policy``/``backfill_depth`` select the scheduler (static here — one
     compile per policy; defaults reproduce the seed worst-fit FCFS exactly).
+    ``cores_per_host`` is one capacity for every host or a tuple of
+    ``num_hosts`` per-host capacities (``DatacenterConfig.host_units``);
+    ``max_gang > 1`` gang-schedules jobs wider than the largest host.
     See :func:`simulate_utilization_masked` for the vmap-able core and
     :mod:`repro.core.scenarios` for the batched what-if engine that sweeps
     policies and topologies in one program.
     """
+    if isinstance(cores_per_host, tuple) and len(cores_per_host) != num_hosts:
+        raise ValueError(f"{len(cores_per_host)} per-host capacities for "
+                         f"{num_hosts} hosts")
     return simulate_utilization_masked(
         w,
         jnp.ones((num_hosts,), jnp.bool_),
-        cores_per_host,
+        jnp.asarray(cores_per_host, jnp.int32),
         max_hosts=num_hosts,
         t_bins=t_bins,
         max_starts_per_bin=max_starts_per_bin,
         policy_id=resolve_policy(policy),
         backfill_depth=backfill_depth,
         max_backfill=int(backfill_depth),
+        max_gang=max_gang,
     )
 
 
@@ -676,6 +841,10 @@ def simulate(
     model: str = "opendc",
 ) -> tuple[SimOutput, Prediction]:
     """One-call trace-in, metrics-out simulation (FR2)."""
+    if dc.host_units is not None:
+        # predict_metrics' utilization mean does not weight hosts by size
+        raise ValueError("simulate() runs fleets of one server size; run a "
+                         "fleet of mixed sizes through run_scenarios")
     sim = simulate_utilization(
         w,
         num_hosts=dc.num_hosts,

@@ -295,10 +295,16 @@ class ScenarioSet:
     ``pue_amb_coeff``       ``[S]`` float32             PUE per °C above ref
     ``pue_amb_ref``         ``[S]`` float32             free-cooling ref °C
     ``pue_load_coeff``      ``[S]`` float32             partial-load penalty
+    ``host_units``          ``[S, H]`` int32 or None    per-host capacity of a
+                                                        fleet of mixed server
+                                                        sizes (None: every host
+                                                        has ``cores_per_host``)
     ======================  ==========================  =====================
 
     ``names`` (tuple of str), ``max_backfill`` (static int: the compile-
-    time backfill window all traced depths are clipped to) and the axis
+    time backfill window all traced depths are clipped to), ``max_gang``
+    (static int: the most whole servers a gang job takes; 1 compiles the
+    gang machinery out) and the axis
     flags ``has_failures`` / ``pue_on`` (static bools: whether the failure /
     dynamic-PUE machinery is compiled in at all) are pytree *aux data* —
     part of the jit cache key, not device arrays.  With a flag off the
@@ -331,6 +337,8 @@ class ScenarioSet:
     max_backfill: int = 0
     has_failures: bool = False
     pue_on: bool = False
+    host_units: Array | None = None   # [S, max_hosts] int32
+    max_gang: int = 1
 
     @property
     def num_scenarios(self) -> int:
@@ -348,10 +356,12 @@ jax.tree_util.register_pytree_node(
                 s.carbon_cap_base_w, s.carbon_cap_slope, s.shift_bins,
                 s.peak_tflops, s.fail_start, s.fail_end, s.fail_kill,
                 s.pue_base, s.pue_amb_coeff, s.pue_amb_ref,
-                s.pue_load_coeff),
-               (s.names, s.max_backfill, s.has_failures, s.pue_on)),
-    lambda aux, c: ScenarioSet(*c, names=aux[0], max_backfill=aux[1],
-                               has_failures=aux[2], pue_on=aux[3]),
+                s.pue_load_coeff, s.host_units),
+               (s.names, s.max_backfill, s.has_failures, s.pue_on,
+                s.max_gang)),
+    lambda aux, c: ScenarioSet(*c[:-1], names=aux[0], max_backfill=aux[1],
+                               has_failures=aux[2], pue_on=aux[3],
+                               host_units=c[-1], max_gang=aux[4]),
 )
 
 
@@ -437,6 +447,7 @@ def build_scenario_set(
     max_backfill: int | None = None,
     has_failures: bool | None = None,
     pue_on: bool | None = None,
+    max_gang: int = 1,
 ) -> ScenarioSet:
     """Stack S candidate configurations against one base trace/topology.
 
@@ -469,9 +480,18 @@ def build_scenario_set(
     scenario uses is sound (sentinel lanes compute identical results);
     forcing one *off* while a scenario uses the axis is rejected.
 
+    A fleet of mixed server sizes (``dc.host_units``) carries its per-host
+    capacities as ``[S, max_hosts]`` rows; its utilization, TFLOP/s and
+    the PUE's load term then weight hosts by capacity.  ``max_gang > 1``
+    gang-schedules jobs wider than the largest server on up to that many
+    whole servers (see :func:`repro.core.desim.simulate_utilization_masked`);
+    it is static, part of the jit cache key, and 1 compiles gangs out.
+
     Raises ``ValueError`` on an empty scenario list, a candidate wanting
-    more hosts than ``max_hosts``, a depth beyond ``max_backfill``, or a
-    failure window on a host the scenario's topology does not have.
+    more hosts than ``max_hosts``, a depth beyond ``max_backfill``, a
+    failure window on a host the scenario's topology does not have, a
+    topology override on a fleet of mixed sizes, or (with gangs on) a job
+    that needs more than ``max_gang`` servers.
     """
     if not scenarios:
         raise ValueError("need at least one scenario")
@@ -484,6 +504,27 @@ def build_scenario_set(
     cores = [sc.cores_per_host if sc.cores_per_host is not None
              else dc.cores_per_host for sc in scenarios]
     names = tuple(sc.name or f"s{i}" for i, sc in enumerate(scenarios))
+    if dc.host_units is not None:
+        for sc, h in zip(scenarios, hosts):
+            if sc.cores_per_host is not None:
+                raise ValueError(
+                    f"scenario {sc.name!r}: cores_per_host cannot be "
+                    "overridden on a fleet of mixed server sizes "
+                    "(DatacenterConfig.host_units gives each host's)")
+            if h > dc.num_hosts:
+                raise ValueError(
+                    f"scenario {sc.name!r}: {h} hosts, but the fleet of "
+                    f"mixed sizes lists capacities for {dc.num_hosts}")
+    if max_gang < 1:
+        raise ValueError(f"max_gang must be >= 1, got {max_gang}")
+    if max_gang > 1:
+        c, v = np.asarray(workload.cores), np.asarray(workload.valid)
+        unit = min(cores)
+        widest = int((-(-c[v] // unit)).max(initial=1))
+        if widest > max_gang:
+            raise ValueError(
+                f"a job needs {widest} whole {unit}-unit servers > "
+                f"max_gang={max_gang}")
 
     # Every scenario perturbs the same base trace, so the stacked workload is
     # assembled host-side in numpy (one device transfer per field) — this
@@ -521,9 +562,18 @@ def build_scenario_set(
         raise ValueError(
             f"scenario wants backfill_depth {max(depths)} > "
             f"max_backfill={mb}")
-    peak = jnp.asarray(
-        [dataclasses.replace(dc, num_hosts=h, cores_per_host=c).peak_tflops
-         for h, c in zip(hosts, cores)], jnp.float32)
+    units = None
+    if dc.host_units is not None:
+        row = np.zeros(mh, np.int32)
+        row[:dc.num_hosts] = dc.host_units
+        units = jnp.asarray(np.stack([np.where(np.arange(mh) < h, row, 0)
+                                      for h in hosts]))
+        peak = jnp.asarray([sum(dc.host_units[:h]) * dc.unit_peak_tflops
+                            for h in hosts], jnp.float32)
+    else:
+        peak = jnp.asarray(
+            [dataclasses.replace(dc, num_hosts=h, cores_per_host=c)
+             .peak_tflops for h, c in zip(hosts, cores)], jnp.float32)
     cap = jnp.asarray(
         [sc.power_cap_w if sc.power_cap_w is not None else math.inf
          for sc in scenarios], jnp.float32)
@@ -601,6 +651,8 @@ def build_scenario_set(
         max_backfill=mb,
         has_failures=bool(has_failures),
         pue_on=bool(pue_on),
+        host_units=units,
+        max_gang=int(max_gang),
     )
 
 
@@ -611,7 +663,8 @@ def _predict_masked(u_th: Array, params: PowerParams, mask: Array,
                     online_th: Array | None = None,
                     pue=None,
                     ambient: Array | None = None,
-                    price: Array | None = None) -> Prediction:
+                    price: Array | None = None,
+                    units: Array | None = None) -> Prediction:
     """Mask-aware :func:`repro.core.desim.predict_metrics` for one scenario.
 
     Padded (inactive) hosts must not dilute mean utilization or draw idle
@@ -640,14 +693,23 @@ def _predict_masked(u_th: Array, params: PowerParams, mask: Array,
         move to *facility* watts — the cap constrains what the meter sees.
     ``price`` (``[T]`` $/kWh)
         Fills ``energy_cost`` from delivered (facility) energy.
+    ``units`` (``[H]``)
+        Per-host capacity of a fleet of mixed server sizes: the mean
+        utilization (and with it TFLOP/s and the PUE's load term) weights
+        each host by its capacity, the share of all units busy.
     """
     maskf = mask.astype(u_th.dtype)
     if online_th is None:
         it_demand = datacenter_power(u_th, params, model=model,
                                      online_mask=maskf)
         idle_floor = jnp.sum(jnp.asarray(params.p_idle, u_th.dtype) * maskf)
-        util_raw = jnp.sum(u_th * maskf, axis=-1) / jnp.maximum(
-            jnp.sum(maskf), 1.0)
+        if units is None:
+            util_raw = jnp.sum(u_th * maskf, axis=-1) / jnp.maximum(
+                jnp.sum(maskf), 1.0)
+        else:
+            wf = maskf * units.astype(u_th.dtype)
+            util_raw = jnp.sum(u_th * wf, axis=-1) / jnp.maximum(
+                jnp.sum(wf), 1.0)
     else:
         onf = online_th.astype(u_th.dtype) * maskf               # [T, H]
         it_demand = datacenter_power(u_th, params, model=model,
@@ -656,8 +718,9 @@ def _predict_masked(u_th: Array, params: PowerParams, mask: Array,
         # contribute neither idle watts nor zero-util dilution
         idle_floor = jnp.sum(
             jnp.asarray(params.p_idle, u_th.dtype) * onf, axis=-1)
-        util_raw = jnp.sum(u_th * onf, axis=-1) / jnp.maximum(
-            jnp.sum(onf, axis=-1), 1.0)
+        wf = onf if units is None else onf * units.astype(u_th.dtype)
+        util_raw = jnp.sum(u_th * wf, axis=-1) / jnp.maximum(
+            jnp.sum(wf, axis=-1), 1.0)
     pue_t = None
     demand = it_demand
     if pue is not None:
@@ -720,10 +783,14 @@ def _scenario_lanes(
         pallas_backend = ("pallas" if jax.devices()[0].platform == "tpu"
                           else "pallas_interpret")
 
+    mixed = ss.host_units is not None
+
     def one(w, mask, cores, policy_id, backfill_depth, params,
             cap_w, carbon_base, carbon_slope, peak,
             fail_start, fail_end, fail_kill,
             pue_base, pue_amb_coeff, pue_amb_ref, pue_load_coeff):
+        # `cores`: the lane's scalar cores per host, or its [H] capacities
+        # on a fleet of mixed sizes
         use_fail = ss.has_failures
         sim = simulate_utilization_masked(
             w, mask, cores,
@@ -735,6 +802,7 @@ def _scenario_lanes(
             fail_start=fail_start if use_fail else None,
             fail_end=fail_end if use_fail else None,
             fail_kill=fail_kill if use_fail else None,
+            max_gang=ss.max_gang,
         )
         with jax.named_scope("opendt.power_readout"):
             # effective per-bin cap: min(static facility cap, carbon-aware
@@ -790,10 +858,12 @@ def _scenario_lanes(
             pred = _predict_masked(sim.u_th, params, mask, peak, model,
                                    cap_t, carbon_intensity,
                                    online_th=online_th, pue=pue,
-                                   ambient=ambient_c, price=price)
+                                   ambient=ambient_c, price=price,
+                                   units=cores if mixed else None)
             return sim, pred
 
-    return jax.vmap(one)(ss.workload, ss.host_mask_s, ss.cores_per_host,
+    return jax.vmap(one)(ss.workload, ss.host_mask_s,
+                         ss.host_units if mixed else ss.cores_per_host,
                          ss.policy_id, ss.backfill_depth, ss.params,
                          ss.power_cap_w, ss.carbon_cap_base_w,
                          ss.carbon_cap_slope, ss.peak_tflops,
@@ -1030,6 +1100,10 @@ def run_scenarios(
         from repro.traces.price import validate_price
         pr = jnp.asarray(
             validate_price(np.asarray(price), t_bins), jnp.float32)
+    if use_pallas and ss.host_units is not None:
+        raise ValueError(
+            "the fused read-out (use_pallas=True) does not weight hosts by "
+            "capacity; run a fleet of mixed server sizes without it")
     s = ss.num_scenarios
     anon = dataclasses.replace(ss, names=("",) * s)
     if not shard:

@@ -27,7 +27,9 @@ class Workload:
     Attributes:
       submit_bin: ``[J] int32`` — submission time, in 5-min bins from t0.
       duration_bins: ``[J] int32`` — runtime in bins (ceil).
-      cores: ``[J] int32`` — cores requested (single-host jobs, <= cores/host).
+      cores: ``[J] int32`` — units requested (cores, or GPUs on an
+        accelerator fleet); wider than a host means a gang of whole servers
+        of the largest size (see :func:`repro.core.desim.simulate_utilization_masked`).
       util_levels: ``[J, U] float32`` — piecewise utilization profile of the
         job over its lifetime, expressed as U equal-length phases of per-core
         utilization in [0, 1] (OpenDC "fragments").
@@ -70,7 +72,23 @@ jax.tree_util.register_pytree_node(
 
 @dataclasses.dataclass(frozen=True)
 class DatacenterConfig:
-    """Static topology of the twinned datacenter (paper §3.2: SURF-SARA)."""
+    """Static topology of the twinned datacenter (paper §3.2: SURF-SARA).
+
+    A fleet of one server size gives ``cores_per_host`` units to every
+    host.  A fleet of mixed sizes (a GPU cluster with 8- and 2-GPU servers)
+    lists each host's capacity in ``host_units``: ``num_hosts`` is then its
+    length and ``cores_per_host`` its largest entry, the server size a gang
+    job takes whole.  ``unit_tflops`` is the peak of one unit (a GPU);
+    unset, a unit is a core at ``ghz`` x ``flops_per_cycle``.
+
+    >>> DatacenterConfig(num_hosts=3, cores_per_host=8, host_units=(8, 2, 2),
+    ...                  unit_tflops=12.0).peak_tflops
+    144.0
+    >>> DatacenterConfig(num_hosts=2, cores_per_host=8, host_units=(8, 2, 2))
+    Traceback (most recent call last):
+        ...
+    ValueError: host_units lists 3 hosts but num_hosts is 2
+    """
 
     num_hosts: int = 277
     cores_per_host: int = 16
@@ -79,13 +97,44 @@ class DatacenterConfig:
     #: double-precision FLOPs per core per cycle (FMA width) — used for the
     #: TFLOPs performance metric in E1's extension (Fig. 5B).
     flops_per_cycle: float = 16.0
+    #: per-host capacity in units, for a fleet of mixed server sizes
+    host_units: tuple[int, ...] | None = None
+    #: peak TFLOP/s of one unit; None: one core's
+    unit_tflops: float | None = None
+
+    def __post_init__(self):
+        if self.host_units is None:
+            return
+        units = tuple(int(u) for u in self.host_units)
+        # a restored checkpoint hands back a list: keep the config hashable
+        object.__setattr__(self, "host_units", units)
+        if len(units) != self.num_hosts:
+            raise ValueError(f"host_units lists {len(units)} hosts but "
+                             f"num_hosts is {self.num_hosts}")
+        if min(units) < 1 or max(units) != self.cores_per_host:
+            raise ValueError(
+                f"host_units must be >= 1 with cores_per_host "
+                f"({self.cores_per_host}) its largest entry, got "
+                f"{min(units)}..{max(units)}")
+
+    @property
+    def unit_peak_tflops(self) -> float:
+        """Peak TFLOP/s of one unit (a core, or the stated ``unit_tflops``)."""
+        if self.unit_tflops is not None:
+            return float(self.unit_tflops)
+        return self.ghz * 1e9 * self.flops_per_cycle / 1e12
 
     @property
     def peak_tflops(self) -> float:
-        """Peak datacenter TFLOP/s at 100 % utilization."""
-        return (
-            self.num_hosts * self.cores_per_host * self.ghz * 1e9 * self.flops_per_cycle
-        ) / 1e12
+        """Peak datacenter TFLOP/s at 100 % utilization: every unit of
+        every host at its peak."""
+        if self.host_units is None and self.unit_tflops is None:
+            return (
+                self.num_hosts * self.cores_per_host * self.ghz * 1e9
+                * self.flops_per_cycle
+            ) / 1e12
+        return sum(self.host_units or (self.cores_per_host,) * self.num_hosts
+                   ) * self.unit_peak_tflops
 
 
 def stack_workloads(ws: "list[Workload] | tuple[Workload, ...]") -> Workload:

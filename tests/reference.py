@@ -52,6 +52,113 @@ def _pick_host(free, need, policy, t, salt, online=None):
     raise ValueError(policy)
 
 
+def _gang_hosts(free, unit, n, policy, t, salt, online=None):
+    """The ``n`` hosts of a gang: whole free ``unit``-hosts that are up,
+    ranked by the policy's score, ties to the lowest index; ``None`` when
+    fewer than ``n`` are free."""
+    whole = [h for h in range(len(free)) if free[h] >= unit
+             and (online is None or online[h])]
+    if len(whole) < n:
+        return None
+    score = {"first_fit": lambda h: -h,
+             "best_fit": lambda h: -free[h],
+             "worst_fit": lambda h: free[h],
+             "random_fit": lambda h: _rand_score(h, t, salt)}[policy]
+    return sorted(whole, key=lambda h: (-score(h), h))[:n]
+
+
+def reference_gang_schedule(submit, dur, cores, valid, *, num_hosts,
+                            cores_per_host, t_bins, policy="worst_fit",
+                            backfill_depth=0, max_starts_per_bin=64,
+                            fail_start=None, fail_end=None, fail_kill=None,
+                            host_capacity=None, max_gang=1):
+    """:func:`reference_schedule` with every host of a job and the count
+    of gang-blocked bins: ``(job_start, job_hosts, gang_blocked_bins)``.
+
+    ``host_capacity`` lists each host's units (default ``cores_per_host``
+    for all).  With ``max_gang > 1`` a job asking for more than ``unit``
+    units, the largest capacity, needs ``ceil(cores / unit)`` (at most
+    ``max_gang``) whole free ``unit``-hosts at once (:func:`_gang_hosts`)
+    and holds each whole.  If an outage host of a running gang fails
+    during its run, the whole job dies at the first such outage start:
+    the outage host's units come back at its ``fail_end``, the others' at
+    once.  ``job_hosts[i]`` lists job ``i``'s hosts (empty if it never
+    started); a gang-blocked bin is one whose placement stopped at a gang
+    head.
+    """
+    j = len(submit)
+    cap = (list(host_capacity) if host_capacity is not None
+           else [cores_per_host] * num_hosts)
+    unit = max(cap)
+    free = list(cap)
+    release = [[0] * num_hosts for _ in range(t_bins + 1)]
+    job_start = [-1] * j
+    job_hosts = [[] for _ in range(j)]
+    blocked_bins = 0
+    next_job = 0
+
+    def is_gang(c):
+        return max_gang > 1 and cores[c] > unit
+
+    def hosts_for(c, t, n, online):
+        if is_gang(c):
+            k = -(-cores[c] // unit)
+            return (None if k > max_gang else
+                    _gang_hosts(free, unit, k, policy, t, n, online))
+        h = _pick_host(free, cores[c], policy, t, n, online)
+        return None if h is None else [h]
+
+    for t in range(t_bins):
+        for h in range(num_hosts):
+            free[h] += release[t][h]
+        online = (None if fail_start is None else
+                  [not (fail_start[h] <= t < fail_end[h])
+                   for h in range(num_hosts)])
+        n = 0
+        while n < max_starts_per_bin:
+            while next_job < j and job_start[next_job] >= 0:
+                next_job += 1
+            if (next_job >= j or submit[next_job] > t
+                    or not valid[next_job]):
+                break
+            jid = next_job
+            hosts = hosts_for(jid, t, n, online)
+            if hosts is None:
+                jid = None
+                for d in range(1, backfill_depth + 1):
+                    c = next_job + d
+                    if c >= j:
+                        break
+                    if (job_start[c] >= 0 or not valid[c]
+                            or submit[c] > t):
+                        continue
+                    hosts = hosts_for(c, t, n, online)
+                    if hosts is not None:
+                        jid = c
+                        break
+                if jid is None:
+                    blocked_bins += is_gang(next_job)
+                    break
+            take = unit if is_gang(jid) else cores[jid]
+            end = t + max(dur[jid], 1)
+            kill = [h for h in hosts if fail_start is not None
+                    and fail_kill[h] and t < fail_start[h] < end]
+            kt = min((fail_start[h] for h in kill), default=None)
+            for h in hosts:
+                free[h] -= take
+                back = end
+                if kill:
+                    # killed at the first outage; the outage host's units
+                    # come back with the host, the others' at once
+                    back = fail_end[h] if fail_start[h] == kt and h in kill \
+                        else kt
+                release[min(back, t_bins)][h] += take
+            job_start[jid] = t
+            job_hosts[jid] = hosts
+            n += 1
+    return job_start, job_hosts, blocked_bins
+
+
 def reference_schedule(submit, dur, cores, valid, *, num_hosts,
                        cores_per_host, t_bins, policy="worst_fit",
                        backfill_depth=0, max_starts_per_bin=64,
@@ -69,55 +176,13 @@ def reference_schedule(submit, dur, cores, valid, *, num_hosts,
     that would run into it dies at ``fail_start[h]`` and its cores return
     with the host at ``fail_end[h]``.
     """
-    j = len(submit)
-    free = [cores_per_host] * num_hosts
-    release = [[0] * num_hosts for _ in range(t_bins + 1)]
-    job_start = [-1] * j
-    job_host = [-1] * j
-    next_job = 0
-
-    for t in range(t_bins):
-        for h in range(num_hosts):
-            free[h] += release[t][h]
-        online = (None if fail_start is None else
-                  [not (fail_start[h] <= t < fail_end[h])
-                   for h in range(num_hosts)])
-        n = 0
-        while n < max_starts_per_bin:
-            while next_job < j and job_start[next_job] >= 0:
-                next_job += 1
-            if (next_job >= j or submit[next_job] > t
-                    or not valid[next_job]):
-                break
-            jid = next_job
-            if _pick_host(free, cores[jid], policy, t, n, online) is None:
-                jid = None
-                for d in range(1, backfill_depth + 1):
-                    c = next_job + d
-                    if c >= j:
-                        break
-                    if (job_start[c] >= 0 or not valid[c]
-                            or submit[c] > t):
-                        continue
-                    if any(free[h] >= cores[c]
-                           and (online is None or online[h])
-                           for h in range(num_hosts)):
-                        jid = c
-                        break
-                if jid is None:
-                    break
-            host = _pick_host(free, cores[jid], policy, t, n, online)
-            free[host] -= cores[jid]
-            job_start[jid] = t
-            job_host[jid] = host
-            end = min(t + max(dur[jid], 1), t_bins)
-            if fail_start is not None and fail_kill[host] \
-                    and t < fail_start[host] < t + max(dur[jid], 1):
-                # killed at the outage; cores come back with the host
-                end = min(fail_end[host], t_bins)
-            release[end][host] += cores[jid]
-            n += 1
-    return job_start, job_host
+    job_start, job_hosts, _ = reference_gang_schedule(
+        submit, dur, cores, valid, num_hosts=num_hosts,
+        cores_per_host=cores_per_host, t_bins=t_bins, policy=policy,
+        backfill_depth=backfill_depth,
+        max_starts_per_bin=max_starts_per_bin, fail_start=fail_start,
+        fail_end=fail_end, fail_kill=fail_kill)
+    return job_start, [hs[0] if hs else -1 for hs in job_hosts]
 
 
 # -- workload perturbation ----------------------------------------------------
@@ -146,7 +211,8 @@ def apply_shift(submit, dur, util, cores, valid, deferrable, shift_bins):
 
 def reference_u_th(job_start, submit, dur, cores, util_levels, job_host, *,
                    num_hosts, cores_per_host, t_bins,
-                   fail_start=None, fail_kill=None):
+                   fail_start=None, fail_kill=None, job_hosts=None,
+                   host_capacity=None):
     """``[t_bins][num_hosts]`` per-host utilization from a schedule.
 
     Replicates the engine's post-scan read-out: a job runs in bins
@@ -156,21 +222,31 @@ def reference_u_th(job_start, submit, dur, cores, util_levels, job_host, *,
     Killed jobs (pre-outage placements on a ``fail_kill`` host that run
     into its window) stop at ``fail_start`` — phase indexing keeps the
     *original* duration, exactly like the engine's ``end_eff`` clamp.
+
+    With ``job_hosts`` (a list of hosts per job, from
+    :func:`reference_gang_schedule`) a gang's cores are spread evenly over
+    its hosts and it stops at the first outage start among them;
+    ``host_capacity`` normalizes each host by its own units.
     """
     j = len(job_start)
     u = [[0.0] * num_hosts for _ in range(t_bins)]
+    cap = (list(host_capacity) if host_capacity is not None
+           else [cores_per_host] * num_hosts)
     phases = len(util_levels[0]) if j else 1
     for i in range(j):
         if job_start[i] < 0:
             continue
+        hosts = job_hosts[i] if job_hosts is not None else [job_host[i]]
         d = max(dur[i], 1)
         end = job_start[i] + d
-        if (fail_start is not None and fail_kill[job_host[i]]
-                and job_start[i] < fail_start[job_host[i]] < end):
-            end = fail_start[job_host[i]]
+        if fail_start is not None:
+            end = min([end] + [fail_start[h] for h in hosts if fail_kill[h]
+                               and job_start[i] < fail_start[h] < end])
         for t in range(job_start[i], min(end, t_bins)):
             ph = min(max((t - job_start[i]) * phases // d, 0), phases - 1)
-            u[t][job_host[i]] += util_levels[i][ph] * cores[i] / cores_per_host
+            for h in hosts:
+                u[t][h] += (util_levels[i][ph] * cores[i] / len(hosts)
+                            / cap[h])
     return u
 
 
@@ -215,7 +291,8 @@ def reference_pue(util_raw, ambient_t, pue):
 def reference_readout(u_th, *, p_idle, p_max, r, power_cap_w=None,
                       carbon_cap_base_w=None, carbon_cap_slope=0.0,
                       intensity=None, sample_seconds=300.0,
-                      online=None, pue=None, ambient=None, price=None):
+                      online=None, pue=None, ambient=None, price=None,
+                      units=None):
     """Masked-readout oracle: demand, enforced cap, throttle, energy, gCO2.
 
     Mirrors ``scenarios._predict_masked`` in plain float64:
@@ -236,7 +313,10 @@ def reference_readout(u_th, *, p_idle, p_max, r, power_cap_w=None,
     * ``pue`` / ``ambient`` — ``(base, amb_coeff, amb_ref, load_coeff)``
       tuple + °C list: demand, idle floor and hence cap enforcement move
       to facility watts (PUE from the *unthrottled* utilization);
-    * ``price``   — ``[T]`` $/kWh: adds ``cost_t = energy_t * price_t``.
+    * ``price``   — ``[T]`` $/kWh: adds ``cost_t = energy_t * price_t``;
+    * ``units``   — ``[H]`` host capacities of a fleet of mixed sizes: the
+      utilization (and the PUE's load term) is the share of online units
+      busy, each host weighted by its capacity.
     """
     t_bins = len(u_th)
     num_hosts = len(u_th[0]) if t_bins else 0
@@ -246,11 +326,17 @@ def reference_readout(u_th, *, p_idle, p_max, r, power_cap_w=None,
         i_t = intensity[t] if intensity is not None else None
         on = online[t] if online is not None else [True] * num_hosts
         n_on = sum(1 for h in range(num_hosts) if on[h])
-        demand = sum(opendc_power(u_th[t][h], p_idle, p_max, r)
+        wt = [1.0] * num_hosts if units is None else units
+        pi = p_idle if isinstance(p_idle, (list, tuple)) else \
+            [p_idle] * num_hosts
+        pm = p_max if isinstance(p_max, (list, tuple)) else \
+            [p_max] * num_hosts
+        demand = sum(opendc_power(u_th[t][h], pi[h], pm[h], r)
                      for h in range(num_hosts) if on[h])
-        idle_floor = p_idle * n_on
-        util_raw = (sum(u_th[t][h] for h in range(num_hosts) if on[h])
-                    / max(n_on, 1))
+        idle_floor = sum(pi[h] for h in range(num_hosts) if on[h])
+        util_raw = (sum(u_th[t][h] * wt[h] for h in range(num_hosts)
+                        if on[h])
+                    / max(sum(wt[h] for h in range(num_hosts) if on[h]), 1))
         pue_t = math.nan
         if pue is not None:
             pue_t = reference_pue(
@@ -334,7 +420,7 @@ def reference_calibrate_per_host(u_th, real_power, candidates, fleet_params,
 
 def reference_scenario(workload, dc, scenario, *, t_bins, p_idle, p_max, r,
                        intensity=None, ambient=None, price=None,
-                       max_starts_per_bin=64):
+                       max_starts_per_bin=64, max_gang=1):
     """Full single-scenario oracle: perturb -> schedule -> readout.
 
     ``workload`` is a dict of plain lists (``submit``, ``dur``, ``cores``,
@@ -347,7 +433,11 @@ def reference_scenario(workload, dc, scenario, *, t_bins, p_idle, p_max, r,
 
     The scenario's failure windows, PUE fields and the ``ambient``/``price``
     traces are threaded through schedule, utilization and read-out exactly
-    like the engine's traced lanes.
+    like the engine's traced lanes.  A fleet of mixed server sizes
+    (``dc.host_units``) schedules against each host's capacity, gangs jobs
+    wider than the largest server when ``max_gang > 1`` and weights the
+    utilization by capacity; ``p_idle``/``p_max`` may then be per-host
+    lists.  ``job_hosts`` lists every job's hosts.
     """
     submit = list(workload["submit"])
     dur = list(workload["dur"])
@@ -388,16 +478,21 @@ def reference_scenario(workload, dc, scenario, *, t_bins, p_idle, p_max, r,
             fe[f.host] = int(f.end_bin)
             fk[f.host] = f.kind == "outage"
 
-    job_start, job_host = reference_schedule(
+    units = (None if dc.host_units is None
+             else list(dc.host_units)[:num_hosts])
+    job_start, job_hosts, gang_blocked = reference_gang_schedule(
         submit, dur, cores, valid, num_hosts=num_hosts,
         cores_per_host=cores_per_host, t_bins=t_bins, policy=policy,
         backfill_depth=int(scenario.backfill_depth),
         max_starts_per_bin=max_starts_per_bin,
-        fail_start=fs, fail_end=fe, fail_kill=fk)
+        fail_start=fs, fail_end=fe, fail_kill=fk, host_capacity=units,
+        max_gang=max_gang)
+    job_host = [hs[0] if hs else -1 for hs in job_hosts]
     u_th = reference_u_th(
         job_start, submit, dur, cores, util, job_host,
         num_hosts=num_hosts, cores_per_host=cores_per_host, t_bins=t_bins,
-        fail_start=fs, fail_kill=fk)
+        fail_start=fs, fail_kill=fk, job_hosts=job_hosts,
+        host_capacity=units)
     online = None
     if fs is not None:
         # power-side availability: only *outage* hosts go dark (drained
@@ -413,9 +508,10 @@ def reference_scenario(workload, dc, scenario, *, t_bins, p_idle, p_max, r,
         power_cap_w=scenario.power_cap_w,
         carbon_cap_base_w=scenario.carbon_cap_base_w,
         carbon_cap_slope=scenario.carbon_cap_slope, intensity=intensity,
-        online=online, pue=pue, ambient=ambient, price=price)
+        online=online, pue=pue, ambient=ambient, price=price, units=units)
     out.update(
-        job_start=job_start, job_host=job_host, submit=submit, u_th=u_th,
+        job_start=job_start, job_host=job_host, job_hosts=job_hosts,
+        gang_blocked_bins=gang_blocked, submit=submit, u_th=u_th,
         waits=[job_start[i] - submit[i] for i in range(len(submit))
                if valid[i] and job_start[i] >= 0])
     return out
