@@ -35,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.power import (
     PowerParams,
@@ -153,6 +154,68 @@ def _policy_host(free: Array, fits: Array, policy_id: Array,
     return jnp.argmax(jnp.where(fits, score, -1))
 
 
+# -- pending core releases ----------------------------------------------------
+# A plain job holds at least one unit of one host until its release, and the
+# units a host holds never exceed its capacity, so a host has at most
+# `capacity` plain releases pending.  Each sits in a slot of its own:
+# [slots, hosts] arrays of the bin its units come back (-1 = empty) and of
+# its units, with `slots` the largest capacity.  A release clipped to the
+# horizon never fires and keeps its slot, as its units stay held.  A
+# [t_bins+1, hosts] table of releases by bin is exact too, but under the
+# scenario vmap its per-bin scatter makes a TPU relayout the whole table
+# every bin.
+
+def _release_slots(slots: int, max_hosts: int) -> tuple[Array, Array]:
+    """Empty release slots: ``(rel_bin, rel_units)``, each
+    ``[slots, max_hosts]`` int32."""
+    return (jnp.full((slots, max_hosts), -1, jnp.int32),
+            jnp.zeros((slots, max_hosts), jnp.int32))
+
+
+def _fire_releases(releases, t: Array):
+    """``(units back per host [H], releases)``: the slots due at bin
+    ``t`` return their units and are emptied."""
+    rel_bin, rel_units = releases
+    fire = rel_bin == t                                             # [K, H]
+    return (jnp.sum(jnp.where(fire, rel_units, 0), axis=0),
+            (jnp.where(fire, -1, rel_bin), rel_units))
+
+
+def _bank_releases(releases, host: Array, end_bin: Array, units: Array):
+    """Bank a bin's placements ``[B]``: entry ``i`` takes the
+    ``r_i``-th empty slot of ``host[i]``, ``r_i`` the number of earlier
+    entries on that host, and holds ``units[i]`` until ``end_bin[i]``.  An
+    entry of 0 units (an unused buffer entry, a gang) takes none.
+
+    Under the scenario vmap a scatter would make a TPU relayout the slots
+    every bin, so both moves between entries and hosts are matrix
+    products with one-hot operands: each sum has at most one nonzero term,
+    an integer below 2**24, so the f32 products at HIGHEST precision are
+    exact.
+    """
+    rel_bin, rel_units = releases
+    n_slots, n_hosts = rel_bin.shape
+    exact = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    take = units > 0                                                # [B]
+    b = jnp.arange(host.shape[0])
+    rank = jnp.sum((host[None, :] == host[:, None]) & take[None, :]
+                   & (b[None, :] < b[:, None]), axis=1)             # [B]
+    on_host = ((host[:, None] == jnp.arange(n_hosts, dtype=host.dtype))
+               & take[:, None]).astype(jnp.float32)                # [B, H]
+    # each entry's host column of empty slots; it takes the one that
+    # brings the count of empties to rank + 1
+    col = exact("bh,kh->bk", on_host,
+                (rel_bin < 0).astype(jnp.float32)).astype(jnp.int32)
+    pick = ((col > 0) & (jnp.cumsum(col, axis=1) == rank[:, None] + 1)
+            & take[:, None]).astype(jnp.float32)                   # [B, K]
+    vals = jnp.stack([end_bin, units]).astype(jnp.float32)          # [2, B]
+    new_bin, new_units = exact("vb,bk,bh->vkh", vals, pick,
+                               on_host).astype(jnp.int32)
+    got = new_units > 0
+    return (jnp.where(got, new_bin, rel_bin),
+            jnp.where(got, new_units, rel_units))
+
+
 @dataclasses.dataclass(frozen=True)
 class SimOutput:
     """Dense simulation read-out at 5-minute granularity.
@@ -233,6 +296,7 @@ def simulate_utilization_masked(
     fail_end: "Array | None" = None,
     fail_kill: "Array | None" = None,
     max_gang: int = 1,
+    max_units: "int | None" = None,
 ) -> SimOutput:
     """Masked-host-axis DES core (trace-level; callers jit/vmap it).
 
@@ -293,6 +357,13 @@ def simulate_utilization_masked(
     machinery out, as ``max_backfill = 0`` does backfill, and then
     ``job_hosts`` and ``gang_blocked_bins`` are ``None``.
 
+    ``max_units`` is the largest host capacity, static: a plain job holds
+    at least one unit of one host until its release, so a host has at
+    most that many plain releases pending, each kept in a slot of its own.
+    ``None`` derives it from a concrete ``cores_per_host``; a traced one
+    needs it (the batch engine carries it from
+    :func:`repro.core.scenarios.build_scenario_set`).
+
     Placement (the event-driven part) is a bounded policy-kernel loop inside
     the scan body; utilization accumulation is a segment-sum scatter over
     host assignments.  Utilization is *independent of power-model
@@ -305,6 +376,19 @@ def simulate_utilization_masked(
     if max_gang < 1:
         raise ValueError(f"max_gang must be >= 1, got {max_gang}")
     gang = max_gang > 1
+    if isinstance(cores_per_host, jax.core.Tracer):
+        if max_units is None:
+            raise ValueError("a traced cores_per_host needs max_units, "
+                             "the largest host capacity")
+    else:
+        # tracecheck: disable=TC002 — concrete here: Tracer excluded above
+        widest_host = int(np.max(np.asarray(cores_per_host)))
+        if max_units is None:
+            max_units = widest_host
+        elif widest_host > max_units:
+            raise ValueError(f"a host holds {widest_host} units > "
+                             f"max_units={max_units}")
+    slots = max(int(max_units), 1)
     j = w.num_jobs
     host_mask = jnp.asarray(host_mask, jnp.bool_)
     cores_per_host = jnp.asarray(cores_per_host, jnp.int32)
@@ -336,13 +420,13 @@ def simulate_utilization_masked(
         gang_ok = n_host <= max_gang
 
     # The scan carries *placement state only*: which job starts where/when,
-    # free cores, a [t_bins+1, max_hosts] core-release table written at
-    # placement time (row t_bins absorbs clipped past-horizon releases), and
-    # a skip bitmask of backfilled jobs ahead of the FCFS pointer.
-    # Everything read out per bin (utilization field, queue depth, running
-    # count) is reconstructed vectorized AFTER the scan from job_start —
-    # per-bin O(jobs) passes inside the scan would dominate the runtime and,
-    # under the scenario vmap, multiply by S with no amortization.
+    # free cores, the pending plain-job releases (`slots` per host, see
+    # _release_slots), and a skip bitmask of backfilled jobs ahead of the
+    # FCFS pointer.  Everything read out per bin (utilization field, queue
+    # depth, running count) is reconstructed vectorized AFTER the scan from
+    # job_start — per-bin O(jobs) passes inside the scan would dominate the
+    # runtime and, under the scenario vmap, multiply by S with no
+    # amortization.
     init = dict(
         free=jnp.where(host_mask, cores_per_host, 0).astype(jnp.int32),
         job_host=jnp.full((j,), -1, jnp.int32),
@@ -352,13 +436,13 @@ def simulate_utilization_masked(
         # never set at rest: every pointer advance immediately consumes the
         # trailing run of set bits, so the head is always an unstarted job.
         skip=jnp.asarray(0, jnp.uint32),
-        release=jnp.zeros((t_bins + 1, max_hosts), jnp.int32),
+        releases=_release_slots(slots, max_hosts),
     )
     if gang:
         # a gang's hosts replace the one host per job.  A gang holds each
         # of its hosts whole, so a host has at most one gang release
         # pending, kept per host (the bin its units come back, -1 = none)
-        # and not in the release table.
+        # and not in the slots.
         del init["job_host"]
         init["job_hosts"] = jnp.full((j, max_gang), -1, jnp.int32)
         init["gang_back"] = jnp.full((max_hosts,), -1, jnp.int32)
@@ -508,8 +592,9 @@ def simulate_utilization_masked(
         return head_ready(next_job, blocked, t) & (n < max_starts_per_bin)
 
     def step(state, t):
-        # 1) completions: cores banked in the release table at placement time.
-        free = state["free"] + state["release"][t]
+        # 1) completions: the units of the slots that come back at t
+        back, releases = _fire_releases(state["releases"], t)
+        free = state["free"] + back
         if gang:
             free = free + jnp.where(state["gang_back"] == t, unit, 0)
 
@@ -535,8 +620,8 @@ def simulate_utilization_masked(
         job_start = state["job_start"].at[buf_jid].set(t, mode="drop")
         end_nom = t + dur[jj]
         if gang:
-            # plain jobs go to the release table by their one host, as
-            # below; gangs were banked in gang_back while placed
+            # plain jobs go to the slots of their one host, as below;
+            # gangs were banked in gang_back while placed
             head = jnp.minimum(next_job, j - 1)
             gang_state = dict(
                 job_hosts=state["job_hosts"].at[buf_jid].set(
@@ -561,11 +646,11 @@ def simulate_utilization_masked(
                 jnp.where(killed, fail_end[buf_host], end_nom), t_bins)
         else:
             end_bin = jnp.minimum(end_nom, t_bins)
-        release = state["release"].at[end_bin, buf_host].add(
-            jnp.where(placed, cores[jj], 0))
+        releases = _bank_releases(releases, buf_host, end_bin,
+                                  jnp.where(placed, cores[jj], 0))
 
         new_state = dict(free=free, job_start=job_start, next_job=next_job,
-                         skip=skip, release=release)
+                         skip=skip, releases=releases)
         new_state.update(gang_state if gang else dict(job_host=job_host))
         return new_state, None
 
@@ -612,7 +697,7 @@ def simulate_utilization_masked(
         elif fail_start is not None:
             # per-job effective end: killed jobs (placed pre-outage on a
             # kill-host, overlapping its window) stop at fail_start.
-            # Mirrors the release-table kill rule above.
+            # Mirrors the kill rule of the scan's release slots above.
             h_j = jnp.where(started, job_host, 0)
             fs_j = fail_start[h_j][:, None]                # [J, 1]
             kill_j = (fail_kill[h_j] & started)[:, None]
@@ -721,6 +806,7 @@ def simulate_utilization(
         backfill_depth=backfill_depth,
         max_backfill=int(backfill_depth),
         max_gang=max_gang,
+        max_units=max(np.atleast_1d(cores_per_host)),
     )
 
 

@@ -325,6 +325,12 @@ class SearchSpace:
             s.num_hosts if s.num_hosts is not None else dc.num_hosts
             for s in self.structures])
 
+    def max_units(self, dc: DatacenterConfig) -> int:
+        """Largest host capacity of every structure plus the baseline."""
+        return max([dc.cores_per_host] + [
+            s.cores_per_host for s in self.structures
+            if s.cores_per_host is not None])
+
     def max_backfill(self) -> int:
         """Static backfill window covering every structure (baseline = 0)."""
         return max([0] + [int(s.backfill_depth) for s in self.structures])
@@ -591,6 +597,7 @@ def optimize(
             "trace was supplied — pass ambient_c=[t_bins] °C")
 
     mh = space.max_hosts(dc)
+    mu = space.max_units(dc)
     mb = space.max_backfill()
     # axis-presence flags are jit cache-key aux on the ScenarioSet: pin them
     # from the *space* (not per batch) so a generation whose mutations happen
@@ -630,7 +637,8 @@ def optimize(
             for i, kn in enumerate(lanes)]
         ss = build_scenario_set(workload, dc, scenarios, base_params,
                                 max_hosts=mh, max_backfill=mb,
-                                has_failures=has_failures, pue_on=pue_on)
+                                max_units=mu, has_failures=has_failures,
+                                pue_on=pue_on)
         # the donating call below invalidates ss's device buffers, so the
         # leaves scoring + the final summaries read live on as a host copy
         ss_host = jax.tree.map(np.asarray, ss)

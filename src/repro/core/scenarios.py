@@ -304,7 +304,8 @@ class ScenarioSet:
     ``names`` (tuple of str), ``max_backfill`` (static int: the compile-
     time backfill window all traced depths are clipped to), ``max_gang``
     (static int: the most whole servers a gang job takes; 1 compiles the
-    gang machinery out) and the axis
+    gang machinery out), ``max_units`` (static int: the largest host
+    capacity of any lane, the DES's release slots per host) and the axis
     flags ``has_failures`` / ``pue_on`` (static bools: whether the failure /
     dynamic-PUE machinery is compiled in at all) are pytree *aux data* —
     part of the jit cache key, not device arrays.  With a flag off the
@@ -339,6 +340,7 @@ class ScenarioSet:
     pue_on: bool = False
     host_units: Array | None = None   # [S, max_hosts] int32
     max_gang: int = 1
+    max_units: int | None = None
 
     @property
     def num_scenarios(self) -> int:
@@ -358,10 +360,11 @@ jax.tree_util.register_pytree_node(
                 s.pue_base, s.pue_amb_coeff, s.pue_amb_ref,
                 s.pue_load_coeff, s.host_units),
                (s.names, s.max_backfill, s.has_failures, s.pue_on,
-                s.max_gang)),
+                s.max_gang, s.max_units)),
     lambda aux, c: ScenarioSet(*c[:-1], names=aux[0], max_backfill=aux[1],
                                has_failures=aux[2], pue_on=aux[3],
-                               host_units=c[-1], max_gang=aux[4]),
+                               host_units=c[-1], max_gang=aux[4],
+                               max_units=aux[5]),
 )
 
 
@@ -448,6 +451,7 @@ def build_scenario_set(
     has_failures: bool | None = None,
     pue_on: bool | None = None,
     max_gang: int = 1,
+    max_units: int | None = None,
 ) -> ScenarioSet:
     """Stack S candidate configurations against one base trace/topology.
 
@@ -486,12 +490,16 @@ def build_scenario_set(
     gang-schedules jobs wider than the largest server on up to that many
     whole servers (see :func:`repro.core.desim.simulate_utilization_masked`);
     it is static, part of the jit cache key, and 1 compiles gangs out.
+    ``max_units``, the largest host capacity of any lane, sizes the DES's
+    release slots; it follows the pinning convention of ``max_hosts``
+    (default: derived from this batch).
 
     Raises ``ValueError`` on an empty scenario list, a candidate wanting
     more hosts than ``max_hosts``, a depth beyond ``max_backfill``, a
     failure window on a host the scenario's topology does not have, a
-    topology override on a fleet of mixed sizes, or (with gangs on) a job
-    that needs more than ``max_gang`` servers.
+    topology override on a fleet of mixed sizes, a host capacity above
+    ``max_units``, or (with gangs on) a job that needs more than
+    ``max_gang`` servers.
     """
     if not scenarios:
         raise ValueError("need at least one scenario")
@@ -562,6 +570,12 @@ def build_scenario_set(
         raise ValueError(
             f"scenario wants backfill_depth {max(depths)} > "
             f"max_backfill={mb}")
+    widest_host = max(dc.host_units) if dc.host_units is not None else max(
+        cores)
+    mu = widest_host if max_units is None else int(max_units)
+    if widest_host > mu:
+        raise ValueError(f"a scenario's host holds {widest_host} units > "
+                         f"max_units={mu}")
     units = None
     if dc.host_units is not None:
         row = np.zeros(mh, np.int32)
@@ -653,6 +667,7 @@ def build_scenario_set(
         pue_on=bool(pue_on),
         host_units=units,
         max_gang=int(max_gang),
+        max_units=mu,
     )
 
 
@@ -803,6 +818,7 @@ def _scenario_lanes(
             fail_end=fail_end if use_fail else None,
             fail_kill=fail_kill if use_fail else None,
             max_gang=ss.max_gang,
+            max_units=ss.max_units,
         )
         with jax.named_scope("opendt.power_readout"):
             # effective per-bin cap: min(static facility cap, carbon-aware

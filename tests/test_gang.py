@@ -155,7 +155,8 @@ def test_vmapped_lane_equals_solo(batch, lane):
     fail = CASES[lane][2]
     solo = jax.jit(functools.partial(
         simulate_utilization_masked, max_hosts=DC.num_hosts, t_bins=T_BINS,
-        max_backfill=ss.max_backfill, max_gang=MAX_GANG))(
+        max_backfill=ss.max_backfill, max_gang=MAX_GANG,
+        max_units=ss.max_units))(
         jax.tree.map(lambda x: x[lane], ss.workload), ss.host_mask_s[lane],
         ss.host_units[lane], policy_id=ss.policy_id[lane],
         backfill_depth=ss.backfill_depth[lane],
@@ -198,7 +199,8 @@ def test_gang_killed_by_one_outage_frees_its_other_hosts():
     fe = np.asarray([0, 15], np.int32)
     fk = np.asarray([False, True])
     sim = jax.jit(functools.partial(
-        simulate_utilization_masked, max_hosts=2, t_bins=40, max_gang=2))(
+        simulate_utilization_masked, max_hosts=2, t_bins=40, max_gang=2,
+        max_units=8))(
         w, np.ones(2, bool), np.asarray([8, 8], np.int32),
         policy_id=0, fail_start=fs, fail_end=fe, fail_kill=fk)
     assert np.asarray(sim.job_start).tolist() == [0, 5, 15]

@@ -11,9 +11,11 @@ time) because only one process at a time may load the TPU library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.kernels.calib_mape import calib_mape_grid_pallas
@@ -25,6 +27,7 @@ V5E_HBM_BYTES = 16 * 10**9
 HOSTS = 277          # SURF-SARA
 WEEK_BINS = 2016     # 7 days of 5-minute bins
 HISTORY_BINS = 144   # 4 windows x 36 bins of calibration history
+LANES = 64           # the what-if cell's batch
 
 
 @pytest.fixture(scope="module")
@@ -103,3 +106,50 @@ def test_calib_mape_compiles_for_v5e(one_chip, hosts, candidates):
     args = [_spec(one_chip, (t, hosts)), _spec(one_chip, (t,))] + [
         _spec(one_chip, (c,)) for _ in range(3)]
     _assert_kernel_fits(calib_mape_grid_pallas.lower(*args).compile())
+
+
+_RESULT = re.compile(r"^\s*(?:ROOT\s+)?%(\S+) = (.*?) ([\w-]+)\(")
+
+
+def test_whatif_scan_moves_no_release_table(one_chip):
+    """The S=64 SURF what-if program for one chip: no reshape, copy or
+    scatter (nor any other instruction) has the ``S * (t_bins+1) * hosts``
+    elements of a table of releases by bin, which the chip would relayout
+    every bin; the pending releases live in per-host slots."""
+    from repro.core import scenarios as sc
+    from repro.runtime.fault import HostFailure
+    from repro.traces.schema import DatacenterConfig
+    from repro.traces.surf import SurfTraceSpec, make_surf22_like
+
+    dc = DatacenterConfig(num_hosts=HOSTS, cores_per_host=16)
+    w = make_surf22_like(SurfTraceSpec(days=7, seed=22), dc)
+    policies = ("worst_fit", "best_fit", "first_fit", "random_fit")
+    lanes = [sc.Scenario(
+        name=f"l{i}", policy=policies[i % 4], backfill_depth=2 * (i % 2),
+        failures=(HostFailure(host=3, start_bin=100, end_bin=400),)
+        if i % 3 == 0 else (), pue_base=1.1 if i % 5 == 0 else None)
+        for i in range(LANES)]
+    ss = sc.build_scenario_set(w, dc, lanes, max_hosts=HOSTS)
+    assert ss.max_units == 16
+    n_jobs = int(ss.workload.submit_bin.shape[-1])
+    chunk = LANES * n_jobs * WEEK_BINS > sc._BATCH_READOUT_THRESHOLD
+    args = jax.tree.map(
+        lambda x: _spec(one_chip, np.shape(x), np.asarray(x).dtype),
+        (ss, np.zeros(WEEK_BINS, np.float32), np.zeros(WEEK_BINS, np.float32),
+         np.zeros(WEEK_BINS, np.float32)))
+    compiled = jax.jit(functools.partial(
+        sc._scenario_lanes, max_hosts=HOSTS, t_bins=WEEK_BINS,
+        max_starts_per_bin=64, model="opendc", chunk=chunk)).lower(
+        *args).compile()
+    table = LANES * (WEEK_BINS + 1) * HOSTS
+    moved = {}
+    for m in map(_RESULT.match, compiled.as_text().splitlines()):
+        if m is None:
+            continue
+        for dims in re.findall(r"\[([\d,]*)\]", m.group(2)):
+            if int(np.prod([int(d) for d in dims.split(",") if d])) == table:
+                moved[m.group(1)] = (m.group(3), dims)
+    assert not moved, f"table-sized instructions: {moved}"
+    ma = compiled.memory_analysis()
+    assert (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes) < V5E_HBM_BYTES
