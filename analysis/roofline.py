@@ -1,11 +1,10 @@
 """Live roofline of the masked-DES hot path (scan vs readout).
 
-:mod:`benchmarks.roofline` tabulates *dry-run artifacts* for the kernel
-experiments; this module instead interrogates the **running** XLA compiler
-about the program every scenario lane actually pays for — the masked
-placement scan (``lax.scan`` over bins driving the policy kernel and the
-failure mask) and the post-scan readout that expands placements into the
-dense ``[T, H]`` utilization grid.
+This module interrogates the **running** XLA compiler about the program
+every scenario lane actually pays for — the masked placement scan
+(``lax.scan`` over bins driving the policy kernel and the failure mask)
+and the post-scan readout that expands placements into the dense
+``[T, H]`` utilization grid.
 
 Per phase it reports:
 

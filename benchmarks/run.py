@@ -6,7 +6,6 @@ human-readable summary per experiment.
   E1  (Fig. 4/5)  reproduce FootPrinter + extend with perf/efficiency
   E2  (Fig. 6)    self-calibration accuracy vs static simulation
   NFR2 (§3.1)     7 days twinned under 1 hour
-  roofline        dry-run-derived roofline table (results/dryrun)
 """
 
 from __future__ import annotations
@@ -24,7 +23,6 @@ import m3sa_metamodel  # noqa: E402
 import e2_calibration  # noqa: E402
 import fleet_bench  # noqa: E402
 import nfr2_speed  # noqa: E402
-import roofline  # noqa: E402
 import serve_bench  # noqa: E402
 import whatif_batch  # noqa: E402
 
@@ -301,16 +299,6 @@ def main() -> None:
         f";bitwise={fl['fleet']['sharded_bitwise_equal']}",
     ))
 
-    cells = roofline.load_cells()
-    summ = roofline.summarize(cells)
-    rows.append((
-        "dryrun_roofline",
-        0.0,
-        f"ok={summ['cells_ok']};skipped={summ['cells_skipped']}"
-        f";errors={summ['cells_error']}"
-        f";dominant={summ['dominant_counts']}",
-    ))
-
     print("name,us_per_call,derived")
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
@@ -324,9 +312,6 @@ def main() -> None:
     print(json.dumps(m3, indent=2))
     print("\n=== NFR2 ===")
     print(json.dumps(n2, indent=2))
-    print("\n=== Roofline (results/dryrun) ===")
-    print(roofline.table(cells))
-    print(json.dumps(summ, indent=2))
     print(f"\n=== What-if snapshot (written to {BENCH_WHATIF}) ===")
     print(json.dumps(wi, indent=2))
     print(f"\n=== DES readout snapshot (written to {BENCH_DES}) ===")

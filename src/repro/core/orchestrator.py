@@ -208,8 +208,8 @@ class Orchestrator:
     """Drives the closed loop over a trace-driven physical twin.
 
     The physical twin is abstracted as the TelemetryStore producer —
-    experiments push synthesized ground truth; the live-training example
-    pushes real measurements from the training run.
+    experiments push synthesized ground truth; a live deployment pushes
+    its measured telemetry the same way.
 
     The windowed math is one ``twin_step`` per window on ``self.state``;
     this object is the I/O shell around it.

@@ -5,11 +5,10 @@ pipelined simulate/calibrate), the SLO monitor and the HITL gate into the
 closed cycle of Figure 1:  telemetry -> twin -> (simulate + calibrate) ->
 SLO-aware feedback -> human-in-the-loop.
 
-Two physical-twin flavors ship with the repo:
-  * ``TraceGroundTruth`` — replays a workload trace with synthesized hidden-
-    model telemetry (experiments E1/E2);
-  * the live-training producer in examples/live_twin_training.py, which
-    pushes measured telemetry from an actual JAX training run.
+The physical twin that ships with the repo is ``TraceGroundTruth``: it
+replays a workload trace with synthesized hidden-model telemetry
+(experiments E1/E2).  Any ``telemetry_source(window, bins)`` that returns
+measured ``TelemetryWindow``s can stand in for it.
 """
 
 from __future__ import annotations

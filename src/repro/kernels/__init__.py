@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the twin: the calibration grid (``calib_mape``) and
+the fused DES read-out (``des_readout``), each with its pure-jnp oracle
+and dispatched through ``ops`` by backend."""

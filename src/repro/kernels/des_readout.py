@@ -68,9 +68,9 @@ def _shape_term(u: Array, r: Array, model: str) -> Array:
     """Power-curve shape term of :data:`repro.core.power.POWER_MODELS`.
 
     ``u`` must be pre-clipped to [0, 1].  The opendc exponent uses the
-    ``exp(r * log(u))`` form (Pallas/TPU has no f32 ``pow`` primitive;
-    same trick as ``power_sim._kernel``) — within 1 ulp of ``u**r`` and
-    exactly reproduced by the XLA reference.
+    ``exp(r * log(u))`` form (Pallas/TPU has no f32 ``pow`` primitive)
+    — within 1 ulp of ``u**r`` and exactly reproduced by the XLA
+    reference.
     """
     if model == "opendc":
         return 2.0 * u - jnp.exp(r * jnp.log(jnp.maximum(u, _LOG_FLOOR)))

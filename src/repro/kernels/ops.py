@@ -3,8 +3,7 @@
 backend = "pallas"           — compiled Pallas (TPU deployment target)
 backend = "pallas_interpret" — Pallas interpret mode (CPU validation; the
                                kernel body runs in Python, semantics identical)
-backend = "xla"              — the pure-jnp oracle (ref.py); used on CPU for
-                               speed and in the multi-pod dry-run lowering.
+backend = "xla"              — the pure-jnp oracle; used on CPU for speed.
 
 The default is resolved from the platform at call time so library code never
 hard-codes a backend.
@@ -15,14 +14,10 @@ from __future__ import annotations
 from typing import Literal
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.calib_mape import calib_mape_grid_pallas
 from repro.kernels.des_readout import des_readout_pallas, des_readout_ref
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.power_sim import power_sim_pallas
-from repro.kernels.ssd_chunk import ssd_chunk_pallas
 
 Array = jax.Array
 Backend = Literal["auto", "pallas", "pallas_interpret", "xla"]
@@ -50,24 +45,6 @@ def calib_mape_grid(
     )
 
 
-def power_sim(
-    u_th: Array, *, p_idle: float, p_max: float, r: float,
-    peak_tflops: float, dt_seconds: float, backend: Backend = "auto",
-) -> tuple[Array, Array, Array]:
-    """Fused (power, energy, tflops) window map."""
-    b = resolve_backend(backend)
-    if b == "xla":
-        return ref.power_sim_ref(
-            u_th, p_idle, p_max, r,
-            peak_tflops=peak_tflops, dt_seconds=dt_seconds,
-        )
-    return power_sim_pallas(
-        u_th, p_idle=p_idle, p_max=p_max, r=r,
-        peak_tflops=peak_tflops, dt_seconds=dt_seconds,
-        interpret=(b == "pallas_interpret"),
-    )
-
-
 def des_readout(u_th: Array, *, backend: Backend = "auto",
                 **kw) -> dict[str, Array]:
     """Fused DES readout: the full per-bin metric set in one pass.
@@ -83,30 +60,3 @@ def des_readout(u_th: Array, *, backend: Backend = "auto",
         return des_readout_ref(u_th, **kw)
     return des_readout_pallas(u_th, interpret=(b == "pallas_interpret"),
                               **kw)
-
-
-def flash_attention(
-    q: Array, k: Array, v: Array, *,
-    causal: bool = True, scale: float | None = None,
-    backend: Backend = "auto",
-) -> Array:
-    """GQA flash attention forward."""
-    b = resolve_backend(backend)
-    if b == "xla":
-        return ref.flash_attention_ref(q, k, v, causal=causal, scale=scale)
-    return flash_attention_pallas(
-        q, k, v, causal=causal, scale=scale,
-        interpret=(b == "pallas_interpret"),
-    )
-
-
-def ssd_chunk(
-    x: Array, dt: Array, a_log: Array, b: Array, c: Array, d_skip: Array,
-    *, backend: Backend = "auto",
-) -> tuple[Array, Array]:
-    """Mamba2/SSD intra-chunk term + boundary states."""
-    bk = resolve_backend(backend)
-    if bk == "xla":
-        return ref.ssd_chunk_ref(x, dt, a_log, b, c, d_skip)
-    return ssd_chunk_pallas(x, dt, a_log, b, c, d_skip,
-                            interpret=(bk == "pallas_interpret"))

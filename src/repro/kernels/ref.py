@@ -1,9 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel.
+"""Pure-jnp oracle for the calibration-grid Pallas kernel.
 
-These are the mathematical specifications: each kernel in this package must
-match its oracle to float tolerance across the shape/dtype sweeps in
-``tests/test_kernels.py``.  The oracles are also the XLA execution path used
-on CPU and in the multi-pod dry-run (kernels/ops.py ``backend="xla"``).
+It is the mathematical specification: ``calib_mape.py`` must match it to
+float tolerance across the shape sweep in ``tests/test_kernels.py``.  It is
+also the XLA execution path used on CPU (kernels/ops.py ``backend="xla"``).
+The read-out kernel's oracle, ``des_readout_ref``, lives beside its kernel
+in ``des_readout.py``: the two share one tile body.
 """
 
 from __future__ import annotations
@@ -54,87 +55,3 @@ def calib_mape_grid_ref(
     ape = jnp.abs((rp - sim) / (jnp.abs(rp) + 1e-9)) * nonzero
     out = jnp.sum(ape, axis=1) * (100.0 / jnp.maximum(n_nz, 1))
     return jnp.where(n_nz > 0, out, jnp.nan)
-
-
-def power_sim_ref(
-    u_th: Array,              # [T, H]
-    p_idle: float | Array,
-    p_max: float | Array,
-    r: float | Array,
-    *,
-    peak_tflops: float,
-    dt_seconds: float,
-) -> tuple[Array, Array, Array]:
-    """Windowed power/energy/TFLOPs map oracle.  Returns ([T], [T], [T])."""
-    u = jnp.clip(u_th.astype(jnp.float32), 0.0, 1.0)
-    h = u.shape[1]
-    shape = 2.0 * u - jnp.exp(
-        jnp.asarray(r, jnp.float32) * jnp.log(jnp.maximum(u, 1e-30))
-    )
-    p_idle = jnp.asarray(p_idle, jnp.float32)
-    p_max = jnp.asarray(p_max, jnp.float32)
-    power = h * p_idle + (p_max - p_idle) * jnp.sum(shape, axis=1)
-    energy = power * (dt_seconds / 3600.0) / 1000.0
-    tflops = jnp.mean(u, axis=1) * peak_tflops
-    return power, energy, tflops
-
-
-def flash_attention_ref(
-    q: Array,   # [B, Hq, S, D]
-    k: Array,   # [B, Hkv, Skv, D]
-    v: Array,   # [B, Hkv, Skv, D]
-    *,
-    causal: bool = True,
-    scale: float | None = None,
-) -> Array:     # [B, Hq, S, D]
-    """Vanilla attention oracle with GQA head grouping."""
-    b, hq, s, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    scale = (d ** -0.5) if scale is None else scale
-    qf = q.astype(jnp.float32) * scale
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    # expand kv heads to q heads
-    kf = jnp.repeat(kf, group, axis=1)
-    vf = jnp.repeat(vf, group, axis=1)
-    logits = jnp.einsum("bhsd,bhtd->bhst", qf, kf)
-    if causal:
-        skv = k.shape[2]
-        # query i attends to keys j <= i + (skv - s)  (supports prefix caches)
-        mask = (jnp.arange(s)[:, None] + (skv - s)) >= jnp.arange(skv)[None, :]
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhst,bhtd->bhsd", probs, vf)
-    return out.astype(q.dtype)
-
-
-def ssd_chunk_ref(
-    x,       # [BC, Q, H, P]
-    dt,      # [BC, Q, H]
-    a_log,   # [H]
-    b,       # [BC, Q, G, N]
-    c,       # [BC, Q, G, N]
-    d_skip,  # [H]
-):
-    """SSD intra-chunk oracle: (y_intra [BC,Q,H,P], states [BC,H,P,N])."""
-    bc, q, h, p = x.shape
-    g = b.shape[2]
-    rep = h // g
-    xf = x.astype(jnp.float32)
-    dtf = dt.astype(jnp.float32)
-    a = -jnp.exp(a_log.astype(jnp.float32))
-    bb = jnp.repeat(b.astype(jnp.float32), rep, axis=2)   # [BC,Q,H,N]
-    cc = jnp.repeat(c.astype(jnp.float32), rep, axis=2)
-    da = dtf * a[None, None, :]
-    csum = jnp.cumsum(da, axis=1)                         # [BC,Q,H]
-    seg = csum[:, :, None, :] - csum[:, None, :, :]       # [BC,Qi,Qj,H]
-    mask = jnp.tril(jnp.ones((q, q), bool))
-    decay = jnp.where(mask[None, :, :, None], jnp.exp(seg), 0.0)
-    cb = jnp.einsum("bqhn,bkhn->bqkh", cc, bb)
-    att = cb * decay * dtf[:, None, :, :]
-    y = jnp.einsum("bqkh,bkhp->bqhp", att, xf)
-    y = y + xf * d_skip.astype(jnp.float32)[None, None, :, None]
-    decay_end = jnp.exp(csum[:, -1:, :] - csum) * dtf     # [BC,Q,H]
-    st = jnp.einsum("bqhp,bqh,bqhn->bhpn", xf, decay_end, bb)
-    return y, st

@@ -1,28 +1,17 @@
-"""Fault-tolerant training driver + host-failure schedules for the DES.
+"""Host-failure schedules for the DES.
 
-Two layers share this module because they model the same physical event
-(a host dying) at different granularities:
-
-* :class:`HostFailure` / :func:`failure_arrays` — *scenario-axis* failure
-  schedules.  A tuple of per-host outage/degradation windows becomes
-  three dense ``[max_hosts]`` arrays (start, end, kill-flag) the batched
-  DES folds into a time-varying host mask, so "rack 3 dies at noon" is
-  one traced lane of a what-if batch.
-* ``run_with_restarts`` — the *training-loop* restart driver a cluster
-  scheduler would run: periodic checkpoints, (optionally injected)
-  failures, restore-from-latest, elastic re-mesh when the surviving
-  device count changed.
+:class:`HostFailure` / :func:`failure_arrays` are *scenario-axis* failure
+schedules.  A tuple of per-host outage/degradation windows becomes three
+dense ``[max_hosts]`` arrays (start, end, kill-flag) the batched DES
+folds into a time-varying host mask, so "rack 3 dies at noon" is one
+traced lane of a what-if batch.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
 
 import numpy as np
-
-from repro.checkpoint import ckpt
-from repro.runtime.elastic import MeshPlan, plan_mesh
 
 #: schedule sentinel for "this host never fails": the window start sits
 #: past any representable bin, so every `start <= t < end` test is false
@@ -91,105 +80,3 @@ def failure_arrays(failures, max_hosts: int
         fe[f.host] = f.end_bin
         kill[f.host] = f.kind == OUTAGE
     return fs, fe, kill
-
-
-@dataclasses.dataclass(frozen=True)
-class FaultConfig:
-    ckpt_dir: str = "/tmp/repro_ckpt"
-    ckpt_every: int = 50            # steps
-    keep: int = 3
-    max_restarts: int = 10
-
-
-@dataclasses.dataclass
-class FailureInjector:
-    """Deterministic failure schedule for tests/examples: kill at steps."""
-
-    fail_at_steps: tuple[int, ...] = ()
-    device_loss: int = 0            # devices lost at each failure
-    _fired: set = dataclasses.field(default_factory=set)
-
-    def check(self, step: int) -> None:
-        if step in self.fail_at_steps and step not in self._fired:
-            self._fired.add(step)
-            raise SimulatedFailure(step, self.device_loss)
-
-
-class SimulatedFailure(RuntimeError):
-    def __init__(self, step: int, device_loss: int):
-        super().__init__(f"simulated node failure at step {step}")
-        self.step = step
-        self.device_loss = device_loss
-
-
-@dataclasses.dataclass
-class RunReport:
-    steps_done: int
-    restarts: int
-    checkpoints: int
-    losses: list[float]
-    restored_from: list[int]
-
-
-def run_with_restarts(
-    *,
-    total_steps: int,
-    make_state: Callable[[], Any],
-    step_fn: Callable[[Any, int], tuple[Any, float]],
-    fault_cfg: FaultConfig = FaultConfig(),
-    injector: FailureInjector | None = None,
-    on_window: Callable[[int, Any], None] | None = None,
-) -> RunReport:
-    """Drive step_fn to total_steps across simulated crashes.
-
-    make_state: fresh job state (params, opt, data cursor, twin state).
-    step_fn(state, step) -> (state', loss).
-    """
-    report = RunReport(0, 0, 0, [], [])
-    restarts = 0
-    while True:
-        start = ckpt.latest_step(fault_cfg.ckpt_dir)
-        if start is None:
-            state = make_state()
-            step0 = 0
-        else:
-            step0, host_state = ckpt.restore(fault_cfg.ckpt_dir)
-            state = _rehydrate(make_state(), host_state)
-            report.restored_from.append(step0)
-        try:
-            for step in range(step0, total_steps):
-                if injector is not None:
-                    injector.check(step)
-                state, loss = step_fn(state, step)
-                report.losses.append(loss)
-                report.steps_done = step + 1
-                if (step + 1) % fault_cfg.ckpt_every == 0:
-                    ckpt.save(fault_cfg.ckpt_dir, step + 1, state,
-                              keep=fault_cfg.keep)
-                    report.checkpoints += 1
-                if on_window is not None:
-                    on_window(step, state)
-            return report
-        except SimulatedFailure:
-            restarts += 1
-            report.restarts = restarts
-            if restarts > fault_cfg.max_restarts:
-                raise
-            # loop: restore from latest checkpoint and continue
-            continue
-
-
-def _rehydrate(template: Any, host_state: Any) -> Any:
-    import jax
-    import jax.numpy as jnp
-
-    flat_t, tdef = jax.tree.flatten(template)
-    flat_h = jax.tree.leaves(host_state)
-    assert len(flat_t) == len(flat_h), "state structure changed across restart"
-    out = []
-    for t, h in zip(flat_t, flat_h):
-        if hasattr(t, "dtype"):
-            out.append(jnp.asarray(np.asarray(h)).astype(t.dtype))
-        else:
-            out.append(h)
-    return tdef.unflatten(out)

@@ -1,8 +1,8 @@
 """Straggler detection driven by the digital twin's step-time prediction.
 
-The twin predicts what a training step *should* cost (roofline-derived
-expectation, continuously re-centered on observed telemetry with the same
-EWMA-style self-calibration idea as the power model).  Hosts whose reported
+The twin predicts what a host's step *should* cost (the fleet's median,
+continuously re-centered on observed telemetry with the same EWMA-style
+self-calibration idea as the power model).  Hosts whose reported
 step times sit far above the calibrated expectation get flagged; the runtime
 proposes RESTART_STRAGGLER through the HITL gate (paper stage 3 semantics —
 the twin recommends, the operator/policy approves).

@@ -1,14 +1,11 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret) vs the pure-jnp oracle."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.calib_mape import calib_mape_grid_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.power_sim import power_sim_pallas
 
 RNG = np.random.default_rng(7)
 
@@ -28,41 +25,6 @@ def test_calib_mape_sweep(t, h, c):
                                rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("t,h", [(96, 17), (300, 277), (1024, 64)])
-def test_power_sim_sweep(t, h):
-    u = jnp.asarray(RNG.uniform(0, 1, (t, h)).astype(np.float32))
-    kw = dict(p_idle=70.0, p_max=350.0, r=2.3, peak_tflops=120.0,
-              dt_seconds=300.0)
-    want = ref.power_sim_ref(u, 70.0, 350.0, 2.3, peak_tflops=120.0,
-                             dt_seconds=300.0)
-    got = power_sim_pallas(u, interpret=True, **kw)
-    for w, g in zip(want, got):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-4, atol=1e-2)
-
-
-@pytest.mark.parametrize("b,hq,hkv,sq,skv,d,causal,dtype", [
-    (1, 4, 4, 128, 128, 64, True, jnp.float32),      # MHA causal
-    (2, 8, 2, 100, 100, 32, True, jnp.float32),      # GQA ragged seq
-    (2, 4, 1, 64, 64, 64, False, jnp.float32),       # MQA bidirectional
-    (1, 6, 2, 1, 96, 64, True, jnp.float32),         # decode shape
-    # tracecheck: disable=TC005 — attention dtype sweep, not twin math
-    (2, 4, 2, 128, 128, 64, True, jnp.bfloat16),     # bf16
-    (1, 4, 4, 257, 257, 16, True, jnp.float32),      # non-tile-aligned
-])
-def test_flash_attention_sweep(b, hq, hkv, sq, skv, d, causal, dtype):
-    q = jnp.asarray(RNG.normal(0, 1, (b, hq, sq, d)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, hkv, skv, d)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, hkv, skv, d)), dtype)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
-    got = flash_attention_pallas(q, k, v, causal=causal, interpret=True,
-                                 q_blk=64, k_blk=64)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5  # tracecheck: disable=TC005 — dtype sweep tolerance
-    np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
-        rtol=tol, atol=tol * 10)
-
-
 def test_ops_backend_dispatch():
     u = jnp.asarray(RNG.uniform(0, 1, (64, 32)).astype(np.float32))
     real = jnp.asarray(RNG.uniform(1e3, 2e3, (64,)).astype(np.float32))
@@ -73,74 +35,3 @@ def test_ops_backend_dispatch():
     b = ops.calib_mape_grid(u, real, pi, pm, c, backend="pallas_interpret")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-3)
     assert ops.resolve_backend("auto") in ("xla", "pallas")
-
-
-@pytest.mark.parametrize("bc,q,h,p,g,n", [
-    (2, 16, 2, 8, 1, 16), (3, 32, 4, 16, 2, 24), (1, 64, 8, 32, 4, 64),
-])
-def test_ssd_chunk_sweep(bc, q, h, p, g, n):
-    x = jnp.asarray(RNG.normal(0, 1, (bc, q, h, p)).astype(np.float32))
-    dt = jnp.asarray(RNG.uniform(0.1, 0.9, (bc, q, h)).astype(np.float32))
-    al = jnp.asarray(RNG.normal(0, 0.3, (h,)).astype(np.float32))
-    b = jnp.asarray(RNG.normal(0, 1, (bc, q, g, n)).astype(np.float32))
-    c = jnp.asarray(RNG.normal(0, 1, (bc, q, g, n)).astype(np.float32))
-    d = jnp.asarray(RNG.normal(0, 1, (h,)).astype(np.float32))
-    y1, s1 = ref.ssd_chunk_ref(x, dt, al, b, c, d)
-    y2, s2 = ops.ssd_chunk(x, dt, al, b, c, d, backend="pallas_interpret")
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(y1),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(np.asarray(s2), np.asarray(s1),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_ssd_chunk_kernel_plus_interchunk_matches_full_ssd():
-    """Kernel intra-chunk + JAX inter-chunk recurrence == models.mamba2
-    full chunked SSD (the kernel is a drop-in for the quadratic part)."""
-    from repro.models.mamba2 import ssd_chunked
-
-    bsz, s, h, p, g, n, q = 2, 64, 4, 8, 2, 16, 16
-    nc = s // q
-    xh = jnp.asarray(RNG.normal(0, 1, (bsz, s, h, p)).astype(np.float32))
-    dt = jnp.asarray(RNG.uniform(0.1, 0.9, (bsz, s, h)).astype(np.float32))
-    al = jnp.asarray(RNG.normal(0, 0.3, (h,)).astype(np.float32))
-    bb = jnp.asarray(RNG.normal(0, 1, (bsz, s, g, n)).astype(np.float32))
-    cc = jnp.asarray(RNG.normal(0, 1, (bsz, s, g, n)).astype(np.float32))
-    dd = jnp.asarray(RNG.normal(0, 1, (h,)).astype(np.float32))
-    want = ssd_chunked(xh, dt, al, bb, cc, dd, q)
-
-    # kernel path: flatten (batch, chunk), run intra-chunk, then recur
-    def chunked(t, trailing):
-        return t.reshape((bsz, nc, q) + trailing)
-
-    xk = chunked(xh, (h, p)).reshape(bsz * nc, q, h, p)
-    dtk = chunked(dt, (h,)).reshape(bsz * nc, q, h)
-    bk = chunked(bb, (g, n)).reshape(bsz * nc, q, g, n)
-    ck = chunked(cc, (g, n)).reshape(bsz * nc, q, g, n)
-    y_intra, states = ops.ssd_chunk(xk, dtk, al, bk, ck, dd,
-                                    backend="pallas_interpret")
-    y_intra = y_intra.reshape(bsz, nc, q, h, p)
-    states = states.reshape(bsz, nc, h, p, n)
-
-    # inter-chunk recurrence + readout (same math as models/mamba2.py)
-    a = -jnp.exp(al)
-    da = dt * a[None, None]
-    csum = jnp.cumsum(da.reshape(bsz, nc, q, h), axis=2)
-    total = csum[:, :, -1]
-    rep = h // g
-    cgrp = jnp.repeat(cc.reshape(bsz, nc, q, g, n), rep, axis=3)
-
-    def scan_fn(state, inp):
-        tot_c, st_c = inp
-        out = state
-        state = state * jnp.exp(tot_c)[:, :, None, None] + st_c
-        return state, out
-
-    import jax as _jax
-    _, prev = _jax.lax.scan(
-        scan_fn, jnp.zeros((bsz, h, p, n), jnp.float32),
-        (total.transpose(1, 0, 2), states.transpose(1, 0, 2, 3, 4)))
-    prev = prev.transpose(1, 0, 2, 3, 4)
-    y_inter = jnp.einsum("bcqhn,bcqh,bchpn->bcqhp", cgrp, jnp.exp(csum), prev)
-    got = (y_intra + y_inter).reshape(bsz, s, h, p)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)

@@ -12,11 +12,9 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.checkpoint import ckpt
 from repro.core.calibrate import CalibrationSpec, calibrate_window
 from repro.core.power import PowerParams, mape, opendc_power
 from repro.core.desim import simulate_utilization
-from repro.data.tokens import DataConfig, TokenPipeline
 from repro.traces.schema import Workload
 
 SETTINGS = dict(max_examples=25, deadline=None)
@@ -92,34 +90,3 @@ def test_calibration_never_worse_than_base(r_true, seed):
     base_mape = float(mape(real, jnp.asarray(np.asarray(
         opendc_power(u, base)).sum(1))))
     assert res.mape <= base_mape + 1e-4
-
-
-@given(step=st.integers(0, 1000), shards=st.sampled_from([1, 2, 4, 8]))
-@settings(**SETTINGS)
-def test_data_pipeline_shards_partition_global_batch(step, shards):
-    cfg = DataConfig(vocab=128, seq_len=16, global_batch=8)
-    pipe = TokenPipeline(cfg)
-    parts = [pipe.batch(step, s, shards)["tokens"] for s in range(shards)]
-    for p in parts:
-        assert p.shape == (8 // shards, 16)
-    again = [pipe.batch(step, s, shards)["tokens"] for s in range(shards)]
-    for a, b in zip(parts, again):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@given(seed=st.integers(0, 2**16))
-@settings(max_examples=10, deadline=None)
-def test_checkpoint_roundtrip(tmp_path_factory, seed):
-    rng = np.random.default_rng(seed)
-    state = {
-        "a": rng.normal(size=(3, 5)).astype(np.float32),
-        "nested": {"b": rng.integers(0, 9, (4,)).astype(np.int32),
-                   "c": float(rng.normal())},
-    }
-    d = tmp_path_factory.mktemp("ck")
-    ckpt.save(str(d), 7, state)
-    step, back = ckpt.restore(str(d))
-    assert step == 7
-    np.testing.assert_array_equal(back["a"], state["a"])
-    np.testing.assert_array_equal(back["nested"]["b"], state["nested"]["b"])
-    assert back["nested"]["c"] == state["nested"]["c"]

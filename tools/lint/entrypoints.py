@@ -95,8 +95,8 @@ OPTIONAL_MODULES: tuple[str, ...] = ("zstandard", "hypothesis")
 
 #: Directories (repo-relative prefixes) where TC007 forbids ambient
 #: nondeterminism: the deterministic heart of the twin.  ``runtime/`` is
-#: included because it produces the traced failure schedules and mesh plans
-#: that what-if results (and their goldens) depend on.  ``serve/`` is the
+#: included because it produces the traced failure schedules that what-if
+#: results (and their goldens) depend on.  ``serve/`` is the
 #: streaming service loop: time is injected (Clock), producers are seeded —
 #: an ambient clock there would break replay determinism silently.
 DETERMINISTIC_DIRS: tuple[str, ...] = (

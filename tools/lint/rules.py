@@ -108,8 +108,8 @@ readout.  Sustainability math (power, energy, gCO2, cost) stays f32: a
 bf16 ulp on a power sum is megawatt-hours of drift over a fleet-year.
 Any `.astype(jnp.bfloat16)`, `astype("bfloat16")` or `jnp.bfloat16`
 reference outside BF16_ALLOWED_FILES (tools/lint/entrypoints.py) is
-flagged.  Model *configs* naming "bfloat16" as a dtype string for the
-training stack are not casts and are not flagged.
+flagged.  A "bfloat16" string outside `astype` (a dtype name in a
+config) is not a cast and is not flagged.
 
 Fix: keep the cast inside src/repro/kernels/des_readout.py behind the
 `precision="bf16"` knob, or extend the allow-list in review.
