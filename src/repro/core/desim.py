@@ -216,6 +216,14 @@ def _bank_releases(releases, host: Array, end_bin: Array, units: Array):
             jnp.where(got, new_units, rel_units))
 
 
+def _put(buf: Array, n: Array, v: Array) -> Array:
+    """``buf`` with entry ``n`` of its leading axis set to ``v``, by a
+    select: under the scenario vmap ``buf.at[n].set(v)`` with a per-lane
+    ``n`` is a scatter."""
+    at = jnp.arange(buf.shape[0]) == n
+    return jnp.where(at.reshape((-1,) + (1,) * (buf.ndim - 1)), v, buf)
+
+
 @dataclasses.dataclass(frozen=True)
 class SimOutput:
     """Dense simulation read-out at 5-minute granularity.
@@ -467,13 +475,15 @@ def simulate_utilization_masked(
     # under vmap, the batched while_loop body re-runs for every lane until
     # all lanes are done and select-freezes every carry leaf per iteration,
     # so carrying the [jobs]-sized state here would cost O(S * jobs) per
-    # attempt.  Instead each attempt records (job, host) into a
-    # [max_starts_per_bin] buffer; the buffers are scattered into the scan
-    # carry once per bin.  Every iteration either places exactly one job or
-    # sets `blocked` (ending the bin), so the loop is bounded by
-    # max_starts_per_bin placements.
+    # attempt.  Instead each attempt records its job, host(s), release bin
+    # and units into [max_starts_per_bin] buffers, written by selects (a
+    # per-lane `.at[n].set` is a scatter under the vmap); the buffers go
+    # into the scan carry once per bin.  Every iteration either places
+    # exactly one job or sets `blocked` (ending the bin), so the loop is
+    # bounded by max_starts_per_bin placements.
     def place_one(carry):
-        free, next_job, skip, blocked, t, n, buf_jid, buf_host = carry[:8]
+        (free, next_job, skip, blocked, t, n, buf_jid, buf_host, buf_end,
+         buf_units) = carry[:10]
         # failed hosts (outage or drain) accept no new placements during
         # their window; sentinel starts make this the plain mask.
         if fail_start is not None:
@@ -523,8 +533,10 @@ def simulate_utilization_masked(
             jid = jid_h
 
         need = cores[jid]
+        end_nom = t + dur[jid]
         fits = (free >= need) & online
         do_place = place_head | place_bf
+        host_idx = jnp.arange(max_hosts, dtype=jnp.int32)
         if gang:
             with jax.named_scope("opendt.gang_select"):
                 # the top n_host of the policy's score among whole hosts
@@ -538,19 +550,30 @@ def simulate_utilization_masked(
                 take = (jnp.arange(max_gang) < n_host[jid]) & do_place
                 hosts = jnp.where(take, top.astype(jnp.int32), -1)
                 # the chosen hosts as a mask: vector selects, no scatter
-                sel = jnp.any(hosts[:, None] == jnp.arange(
-                    max_hosts, dtype=jnp.int32)[None, :], axis=0)    # [H]
+                sel = jnp.any(hosts[:, None] == host_idx[None, :],
+                              axis=0)                                # [H]
                 free = free - jnp.where(sel, jnp.where(g, unit, need), 0)
-                gang_back = jnp.where(sel & g,
-                                      _gang_back(sel, t, t + dur[jid]),
-                                      carry[8])
-            buf_host = buf_host.at[n].set(hosts)
+                back = _release_back(sel, t, end_nom)
+                gang_back = jnp.where(sel & g, back, carry[10])
+            placed_plain = do_place & jnp.logical_not(g)
         else:
             host = _policy_host(free, fits, policy_id, t,
                                 jnp.asarray(n, jnp.int32), max_hosts)
             free = free.at[host].add(jnp.where(do_place, -need, 0))
-            buf_host = buf_host.at[n].set(host)
-        buf_jid = buf_jid.at[n].set(jnp.where(do_place, jid, j))
+            sel = host_idx == host
+            back = _release_back(sel, t, end_nom)
+            hosts = host
+            placed_plain = do_place
+        # a placed plain job's release bin on its one host; a gang's went
+        # to gang_back above, and an entry of 0 units banks nothing
+        if fail_start is not None:
+            end_bin = jnp.max(jnp.where(sel, back, 0))
+        else:
+            end_bin = jnp.minimum(end_nom, t_bins)
+        buf_host = _put(buf_host, n, hosts)
+        buf_jid = _put(buf_jid, n, jnp.where(do_place, jid, j))
+        buf_end = _put(buf_end, n, end_bin)
+        buf_units = _put(buf_units, n, jnp.where(placed_plain, need, 0))
 
         if max_backfill > 0:
             # head placed: advance past it and any backfilled successors.
@@ -569,15 +592,17 @@ def simulate_utilization_masked(
             blocked = blocked | (eligible & jnp.logical_not(head_fits))
 
         return (free, next_job, skip, blocked, t,
-                n + do_place.astype(jnp.int32), buf_jid,
-                buf_host) + ((gang_back,) if gang else ())
+                n + do_place.astype(jnp.int32), buf_jid, buf_host, buf_end,
+                buf_units) + ((gang_back,) if gang else ())
 
-    def _gang_back(sel, t, end_nom):
-        """Per host, the bin a gang placed at ``t`` on the hosts ``sel``
-        gives them back: its end, or under the gang kill rule (the job
-        dies at the first outage start among its hosts that falls inside
-        its run) that outage's start, and the outage host's own
-        ``fail_end`` for that host.  Clipped to the horizon."""
+    def _release_back(sel, t, end_nom):
+        """Per host, the bin a job placed at ``t`` on the hosts ``sel``
+        (one for a plain job) gives them back: its end, or under the kill
+        rule (the job dies at the first outage start among its hosts that
+        falls inside its run) that outage's start, and the outage host's
+        own ``fail_end`` for that host.  Clipped to the horizon.  The
+        ``t < fail_start`` guard keeps post-recovery placements alive (for
+        them ``t >= fail_end > fail_start``)."""
         back = jnp.broadcast_to(end_nom, sel.shape)
         if fail_start is not None:
             kill = sel & fail_kill & (t < fail_start) & (end_nom > fail_start)
@@ -605,23 +630,19 @@ def simulate_utilization_masked(
         buf_jid = jnp.full((max_starts_per_bin,), j, jnp.int32)
         buf_host = (jnp.full((max_starts_per_bin, max_gang), -1, jnp.int32)
                     if gang else jnp.zeros((max_starts_per_bin,), jnp.int32))
-        (free, next_job, skip, blocked, _, _, buf_jid,
-         buf_host, *gang_back) = jax.lax.while_loop(
+        buf_zero = jnp.zeros((max_starts_per_bin,), jnp.int32)
+        (free, next_job, skip, blocked, _, _, buf_jid, buf_host, buf_end,
+         buf_units, *gang_back) = jax.lax.while_loop(
             keep_placing, place_one,
             (free, state["next_job"], state["skip"], jnp.asarray(False), t,
-             jnp.asarray(0, jnp.int32), buf_jid, buf_host)
-            + ((state["gang_back"],) if gang else ()),
+             jnp.asarray(0, jnp.int32), buf_jid, buf_host, buf_zero,
+             buf_zero) + ((state["gang_back"],) if gang else ()),
         )
 
         # 3) apply this bin's placements (unused buffer slots hold the
         # out-of-bounds sentinel job id j and are dropped by the scatter).
-        jj = jnp.minimum(buf_jid, j - 1)
-        placed = buf_jid < j
         job_start = state["job_start"].at[buf_jid].set(t, mode="drop")
-        end_nom = t + dur[jj]
         if gang:
-            # plain jobs go to the slots of their one host, as below;
-            # gangs were banked in gang_back while placed
             head = jnp.minimum(next_job, j - 1)
             gang_state = dict(
                 job_hosts=state["job_hosts"].at[buf_jid].set(
@@ -629,25 +650,11 @@ def simulate_utilization_masked(
                 gang_back=gang_back[0],
                 gang_blocked=state["gang_blocked"]
                 + (blocked & is_gang[head]).astype(jnp.int32))
-            placed = placed & jnp.logical_not(is_gang[jj])
             buf_host = jnp.maximum(buf_host[:, 0], 0)
         else:
             job_host = state["job_host"].at[buf_jid].set(buf_host,
                                                          mode="drop")
-        if fail_start is not None:
-            # kill rule, applied at placement time: a job landing on a
-            # kill-host *before* its outage and running into it dies at
-            # fail_start, and its cores come back with the host at
-            # fail_end.  The `t < fail_start` guard keeps post-recovery
-            # placements alive (for them t >= fail_end > fail_start).
-            killed = (fail_kill[buf_host] & (t < fail_start[buf_host])
-                      & (end_nom > fail_start[buf_host]))
-            end_bin = jnp.minimum(
-                jnp.where(killed, fail_end[buf_host], end_nom), t_bins)
-        else:
-            end_bin = jnp.minimum(end_nom, t_bins)
-        releases = _bank_releases(releases, buf_host, end_bin,
-                                  jnp.where(placed, cores[jj], 0))
+        releases = _bank_releases(releases, buf_host, buf_end, buf_units)
 
         new_state = dict(free=free, job_start=job_start, next_job=next_job,
                          skip=skip, releases=releases)
